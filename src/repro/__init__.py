@@ -1,10 +1,4 @@
 """repro: Sawtooth Wavefront Reordering as a first-class feature of a
 JAX/TPU training+serving framework. See DESIGN.md."""
 
-# Install jax forward-compat shims (no-ops on modern jax) before any
-# submodule — or test code — touches the newer API surface.
-from repro import _compat as _compat
-
-_compat.install()
-
 __version__ = "1.0.0"

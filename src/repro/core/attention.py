@@ -431,7 +431,7 @@ def decode_attention(
     over absolute positions.
 
     Paged layout (``block_table`` given): caches are shared page pools
-    (n_pages, page, Hkv, D); ``block_table`` (B, n_blocks) maps each row's
+    (n_pages, Hkv, page, D); ``block_table`` (B, n_blocks) maps each row's
     logical page j to a physical pool page, and pages are visited in
     ``KVSchedule`` order (``order='sawtooth'`` alternates direction per
     decode step, parity keyed on ``cache_len``). The paged path is ragged:
@@ -494,7 +494,7 @@ def paged_decode_attention(
 
     q: (B, C, Hq, D) — a ragged chunk of C query positions per row (C=1 is
     plain decode; C>1 is a chunked-prefill / mixed serve step).
-    k_pool/v_pool: (n_pages, page, Hkv, D) — one shared pool across the
+    k_pool/v_pool: (n_pages, Hkv, page, D) — one shared pool across the
     batch. block_table: (B, n_blocks) int32, logical page j of row b lives
     in pool page ``block_table[b, j]``. cache_len: (B,) or scalar valid KV
     lengths *including* this chunk's writes. q_lens: (B,) number of valid
@@ -516,7 +516,7 @@ def paged_decode_attention(
     continuous-batching pool) return exact zeros rather than NaN.
     """
     b, c, hq, d = q.shape
-    n_pages, page, hkv, _ = k_pool.shape
+    n_pages, hkv, page, _ = k_pool.shape
     n_blocks = block_table.shape[1]
     g = hq // hkv
     scale_ = d ** -0.5 if scale is None else scale
@@ -554,7 +554,7 @@ def paged_decode_attention(
         m, l, acc = carry
         logical = jax.lax.dynamic_index_in_dim(visit, j, axis=1, keepdims=False)
         pid = jax.lax.dynamic_index_in_dim(phys, j, axis=1, keepdims=False)
-        k_j = k_pool[pid].astype(jnp.float32)  # (B, page, Hkv, D)
+        k_j = k_pool[pid].astype(jnp.float32)  # (B, Hkv, page, D)
         v_j = v_pool[pid].astype(jnp.float32)
         pos = logical[:, None] * page + offs   # (B, page) absolute positions
         # (B, C, page): kv visible to query row t iff within [0, len),
@@ -565,13 +565,13 @@ def paged_decode_attention(
         if window is not None:
             valid &= pos[:, None, :] > (q_pos[:, :, None] - window)
         ok = valid[:, None, None, :, :]        # (B, 1, 1, C, page)
-        s = jnp.einsum("bhgcd,bkhd->bhgck", qf, k_j)
+        s = jnp.einsum("bhgcd,bhkd->bhgck", qf, k_j)
         s = jnp.where(ok, s, NEG_INF)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum("bhgck,bkhd->bhgcd", p, v_j)
+        acc_new = acc * alpha[..., None] + jnp.einsum("bhgck,bhkd->bhgcd", p, v_j)
         return (m_new, l_new, acc_new), None
 
     init = (

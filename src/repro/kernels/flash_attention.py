@@ -47,15 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # jax >= 0.7 name, with fallback for older spellings
-    from jax.experimental.pallas import tpu as pltpu
-
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import Order, Traversal
 
@@ -284,11 +276,6 @@ def flash_attention_fwd(
     )
 
     grid = (b * hkv, g * nq, nkv)
-    compiler_params = None
-    if _CompilerParams is not None and not interpret:
-        compiler_params = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")
-        )
 
     out_shape = [jax.ShapeDtypeStruct((b * hkv, g * sq_p, dp), q.dtype)]
     out_specs = [pl.BlockSpec((1, q_block, dp), q_map)]
@@ -312,7 +299,9 @@ def flash_attention_fwd(
             pltpu.VMEM((q_block, dp), jnp.float32),
         ],
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        ),
     )(qf, kf, vf)
 
     out = outs[0].reshape(b, hkv, g, sq_p, dp)[:, :, :, :sq, :d]
@@ -544,14 +533,11 @@ def flash_attention_bwd(
         return (bh, i, 0)
 
     interp = {"interpret": interpret}
-    if _CompilerParams is not None and not interpret:
-        compiler3 = {
-            "compiler_params": _CompilerParams(
-                dimension_semantics=("parallel", "arbitrary", "arbitrary")
-            )
-        }
-    else:
-        compiler3 = {}
+    compiler3 = {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+        )
+    }
 
     # ---- delta = rowsum(dO * O) ---------------------------------------------
     delta_f = pl.pallas_call(

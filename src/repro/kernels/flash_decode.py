@@ -2,7 +2,7 @@
 
 Used by ``serve_step`` for the decode_32k / long_500k shapes. The KV cache is
 streamed chunk-by-chunk with online softmax; per-batch valid lengths and
-sliding windows are carried by a precomputed (B, S_max) mask operand so the
+sliding windows are carried by a precomputed (B, 1, S_max) mask operand so the
 kernel needs no scalar plumbing.
 
 In the contiguous layout, sawtooth alternates the chunk-scan direction
@@ -31,15 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import (
     Order,
@@ -95,7 +87,7 @@ def _decode_kernel(
     q_ref,  # (1, Gp, D)
     k_ref,  # (1, ck, D)
     v_ref,
-    mask_ref,  # (1, ck) f32 0/1
+    mask_ref,  # (1, 1, ck) f32 0/1
     o_ref,  # (1, Gp, D)
     m_scr,
     l_scr,
@@ -108,7 +100,7 @@ def _decode_kernel(
         q_ref[0],
         k_ref[0],
         v_ref[0],
-        (mask_ref[0] > 0.0)[None, :],
+        mask_ref[0] > 0.0,
         o_ref,
         m_scr,
         l_scr,
@@ -124,7 +116,7 @@ def _paged_decode_kernel(
     logical_ref,  # scalar prefetch: (B, n_blocks) visit-ordered logical page ids
     meta_ref,     # scalar prefetch: (B, 2) per-row [cache_len, q_len]
     q_ref,  # (1, CGp, D) — C chunk rows × G GQA rows, query-major
-    k_ref,  # (1, page, 1, D) one pool page, one kv head
+    k_ref,  # (1, 1, page, D) one pool page, one kv head
     v_ref,
     o_ref,  # (1, CGp, D)
     m_scr,
@@ -158,8 +150,8 @@ def _paged_decode_kernel(
         ok &= col > q_pos - window
     _decode_step(
         q_ref[0],
-        k_ref[0, :, 0, :],
-        v_ref[0, :, 0, :],
+        k_ref[0, 0],
+        v_ref[0, 0],
         ok,
         o_ref,
         m_scr,
@@ -190,7 +182,7 @@ def flash_decode_fwd(
     """q (B,1,Hq,D); caches (B,S_max,Hkv,D); cache_len scalar or (B,).
 
     With ``block_table`` (B, n_blocks), caches are shared page pools
-    (n_pages, page, Hkv, D) and the kernel visits each row's pages through
+    (n_pages, Hkv, page, D) and the kernel visits each row's pages through
     the block table in schedule order; q may then carry C > 1 ragged chunk
     positions per row with per-row ``q_lens`` (see
     :func:`paged_flash_decode_fwd`). ``order_group`` (paged only) replaces
@@ -257,8 +249,10 @@ def _flash_decode_contiguous(
     ok = pos < lens[:, None]
     if window is not None:
         ok &= pos > (lens[:, None] - 1 - window)
-    mask = ok.astype(jnp.float32)  # (B, S_max)
-    mask = _pad_axis(mask, 1, chunk)
+    # (B, 1, S_max): a unit row axis keeps the mask block's last two dims
+    # (1, chunk) legal on TPU for any batch size.
+    mask = ok.astype(jnp.float32)[:, None, :]
+    mask = _pad_axis(mask, 2, chunk)
 
     g_pad = max(8, g)
     qf = q.reshape(b, hkv, g, d).reshape(b * hkv, g, d)
@@ -287,14 +281,9 @@ def _flash_decode_contiguous(
         return (bh, kv_index(order, bh, c, n_chunks, snake_group=snake_group), 0)
 
     def mask_map(bh, c):
-        return (bh // hkv, kv_index(order, bh, c, n_chunks, snake_group=snake_group))
+        return (bh // hkv, 0, kv_index(order, bh, c, n_chunks, snake_group=snake_group))
 
     kernel = functools.partial(_decode_kernel, n_chunks=n_chunks, scale=scale_)
-    compiler_params = None
-    if _CompilerParams is not None and not interpret:
-        compiler_params = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
 
     out = pl.pallas_call(
         kernel,
@@ -303,7 +292,7 @@ def _flash_decode_contiguous(
             pl.BlockSpec((1, g_pad, dp), q_map),
             pl.BlockSpec((1, chunk, dp), kv_map),
             pl.BlockSpec((1, chunk, dp), kv_map),
-            pl.BlockSpec((1, chunk), mask_map),
+            pl.BlockSpec((1, 1, chunk), mask_map),
         ],
         out_specs=pl.BlockSpec((1, g_pad, dp), q_map),
         out_shape=jax.ShapeDtypeStruct((b * hkv, g_pad, dp), q.dtype),
@@ -313,7 +302,9 @@ def _flash_decode_contiguous(
             pltpu.VMEM((g_pad, dp), jnp.float32),
         ],
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
     )(qf, kf, vf, mask)
 
     out = out.reshape(b, hkv, g_pad, dp)[:, :, :g, :d]
@@ -339,7 +330,7 @@ def paged_flash_decode_fwd(
     interpret: bool = False,
     order_group: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Ragged paged attention: q (B,C,Hq,D); pools (n_pages, page, Hkv, D).
+    """Ragged paged attention: q (B,C,Hq,D); pools (n_pages, Hkv, page, D).
 
     C = 1 is plain decode; C > 1 is a chunked-prefill / mixed serve step,
     with per-row ``q_lens`` valid chunk rows and causal masking *inside*
@@ -354,10 +345,14 @@ def paged_flash_decode_fwd(
     pattern. Validity/causality is computed *in-kernel* from two more
     scalar-prefetch operands (the visit-ordered logical ids and per-row
     (cache_len, q_len)), so no O(B·n_blocks·C·page) mask operand exists.
+
+    The pool puts the kv-head axis before the page rows, so each grid step
+    reads one ``(page, D)`` tile — the block's last two dims equal the
+    array's, which is what the TPU lowering requires for any page size.
     """
     order = Order.parse(order)
     b, c, hq, d = q.shape
-    n_pages, page, hkv, _ = k_pool.shape
+    n_pages, hkv, page, _ = k_pool.shape
     n_blocks = block_table.shape[1]
     g = hq // hkv
     scale_ = float(d**-0.5 if scale is None else scale)
@@ -402,7 +397,7 @@ def paged_flash_decode_fwd(
         return (bh, 0, 0)
 
     def kv_map(bh, j, phys_ref, logical_ref, meta_ref):
-        return (phys_ref[bh // hkv, j], 0, bh % hkv, 0)
+        return (phys_ref[bh // hkv, j], bh % hkv, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -413,19 +408,13 @@ def paged_flash_decode_fwd(
         hkv=hkv,
         window=window,
     )
-    compiler_params = None
-    if _CompilerParams is not None and not interpret:
-        compiler_params = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b * hkv, n_blocks),
         in_specs=[
             pl.BlockSpec((1, cg_pad, dp), q_map),
-            pl.BlockSpec((1, page, 1, dp), kv_map),
-            pl.BlockSpec((1, page, 1, dp), kv_map),
+            pl.BlockSpec((1, 1, page, dp), kv_map),
+            pl.BlockSpec((1, 1, page, dp), kv_map),
         ],
         out_specs=pl.BlockSpec((1, cg_pad, dp), q_map),
         scratch_shapes=[
@@ -439,7 +428,9 @@ def paged_flash_decode_fwd(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, cg_pad, dp), q.dtype),
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
     )(phys, visit, meta, qf, kf, vf)
 
     out = out[:, :cg, :d].reshape(b, hkv, c, g, d)

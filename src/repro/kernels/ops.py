@@ -22,6 +22,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import attention as core_attn
 from repro.core.schedule import Order
@@ -47,16 +48,56 @@ def _resolve(impl: Impl) -> str:
     return default_impl() if impl == "auto" else impl
 
 
+def _mesh_split(b: int, hkv: int):
+    """(batch axes, head axes) of the ambient mesh for a Pallas call, or
+    None outside a multi-device mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so under a mesh each device
+    runs the kernel on its own slice inside a ``shard_map``: mesh axes go
+    to the batch while their product divides it, then to the kv heads;
+    an axis that divides neither leaves the operands replicated along it.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return None
+    batch, heads, nb, nh = [], [], 1, 1
+    for name, size in mesh.shape.items():
+        if size == 1:
+            continue
+        if b % (nb * size) == 0:
+            batch.append(name)
+            nb *= size
+        elif hkv % (nh * size) == 0:
+            heads.append(name)
+            nh *= size
+    return tuple(batch) or None, tuple(heads) or None
+
+
+def _per_shard(call, *args, out_ranks=(4,)):
+    """``call(*args)`` shard by shard on the ambient mesh (see
+    :func:`_mesh_split`); a plain call off-mesh. Operands and results are
+    (B, S, H, D) arrays or (B, S, Hq) lse rows, told apart by rank."""
+    split = _mesh_split(args[0].shape[0], args[1].shape[2])
+    if split is None:
+        return call(*args)
+    spec = {4: P(split[0], None, split[1], None), 3: P(split[0], None, split[1])}
+    out = tuple(spec[r] for r in out_ranks)
+    return jax.shard_map(
+        call,
+        in_specs=tuple(spec[a.ndim] for a in args),
+        out_specs=out if len(out) > 1 else out[0],
+        check_vma=False,
+    )(*args)
+
+
 def _fwd_dispatch(
     q, k, v, *, impl, order, causal, window, scale, q_block, kv_block, score_dtype,
     snake_group, return_lse=False,
 ):
     impl = _resolve(impl)
     if impl in ("pallas", "pallas_interpret"):
-        return flash_attention_fwd(
-            q,
-            k,
-            v,
+        call = functools.partial(
+            flash_attention_fwd,
             order=order,
             causal=causal,
             window=window,
@@ -67,6 +108,7 @@ def _fwd_dispatch(
             interpret=(impl == "pallas_interpret"),
             return_lse=return_lse,
         )
+        return _per_shard(call, q, k, v, out_ranks=(4, 3) if return_lse else (4,))
     if impl in ("xla", "jnp"):
         return core_attn.flash_attention(
             q,
@@ -145,8 +187,8 @@ def _make_attention(
         q, k, v, o, lse = res
         r = _resolve(impl)
         if r in ("pallas", "pallas_interpret"):
-            return kflash.flash_attention_bwd(
-                q, k, v, o, lse, g,
+            call = functools.partial(
+                kflash.flash_attention_bwd,
                 order=order,
                 causal=causal,
                 window=window,
@@ -156,6 +198,7 @@ def _make_attention(
                 snake_group=snake_group,
                 interpret=(r == "pallas_interpret"),
             )
+            return _per_shard(call, q, k, v, o, lse, g, out_ranks=(4, 4, 4))
         if r == "xla":
             return core_attn.flash_attention_bwd(
                 q, k, v, o, lse, g,
@@ -237,7 +280,7 @@ def attention_decode(
     """Decode / ragged-chunk attention vs a KV cache. Not differentiated.
 
     ``block_table`` switches both backends to the paged layout: caches are
-    shared (n_pages, page, Hkv, D) pools and pages are visited in schedule
+    shared (n_pages, Hkv, page, D) pools and pages are visited in schedule
     order through the table (sawtooth parity keyed per row on
     ``cache_len``). The paged layout is ragged: q may carry C > 1 chunk
     positions per row with per-row ``q_lens`` valid rows and causal masking

@@ -24,15 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["ssd_fwd"]
 
@@ -134,11 +126,6 @@ def ssd_fwd(
     ).reshape(bsz * h, p, n)
 
     kernel = functools.partial(_ssd_kernel, n_chunks=nz, chunk=chunk)
-    compiler_params = None
-    if _CompilerParams is not None and not interpret:
-        compiler_params = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
 
     def bh_map(bh, z):
         return (bh, z, 0)
@@ -173,7 +160,9 @@ def ssd_fwd(
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
     )(xf, daf, dtf, b, c, init)
 
     y = y.reshape(bsz, h, sp, p).transpose(0, 2, 1, 3)[:, :s]

@@ -29,7 +29,9 @@ re-prefill restore, bounded by ``--max-preemptions``), ``--max-queue``
 load-sheds the newest arrived requests, ``--admit-watermark`` pauses
 admission under pool pressure, and ``--deadline-s`` gives every synthetic
 request a wall-clock deadline. Every request resolves with a typed
-``status`` (ok/deadline/cancelled/shed/failed) instead of raising.
+``status`` (ok/deadline/cancelled/shed/failed) instead of raising; the
+launcher exits non-zero when any request ends ``failed``, and an error out
+of the device step other than an injected ``StepFault`` propagates.
 
 Tiered KV memory (DESIGN.md §13): ``--host-pages N`` backs the device pool
 with an N-page host tier — at ``--spill-watermark`` occupancy the engine
@@ -61,6 +63,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.schedule import Order
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serve import FaultPlan, Request, ServeEngine, supports_continuous
 from repro.train.checkpoint import latest_step, restore_pytree
@@ -78,7 +81,14 @@ def pick_scheduler(choice: str, cfg) -> str:
     return "continuous" if ok else "static"
 
 
-def main():
+def init_params(lm, seed: int):
+    """Random weights from ``seed``, built on the device under jit: no f32
+    draw of a stacked leaf is ever materialised (eagerly, one deepseek-7b
+    MLP leaf alone is a 5 GiB f32 draw plus its scaled copy)."""
+    return jax.jit(lm.init)(jax.random.PRNGKey(seed))
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -193,7 +203,8 @@ def main():
                          "default matches hillclimb --sweep-orders)")
     ap.add_argument("--log-every", type=int, default=0, metavar="STEPS",
                     help="print a one-line stats summary every N mixed steps")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.attn_order == "block_snake" and args.snake_group is None:
         valid = ", ".join(repr(o.value) for o in Order) + ", 'auto'"
@@ -212,7 +223,7 @@ def main():
         cfg = cfg.with_(attn_order=args.attn_order)
     cfg = cfg.with_(snake_group=args.snake_group)
     lm = build_model(cfg)
-    params = lm.init(jax.random.PRNGKey(0))
+    params = init_params(lm, 0)
     if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
         state, step = restore_pytree({"params": params}, args.ckpt_dir)
         params = state["params"]
@@ -228,7 +239,7 @@ def main():
             if args.reduced:
                 draft_cfg = draft_cfg.reduced()
             draft_lm = build_model(draft_cfg)
-            draft_params = draft_lm.init(jax.random.PRNGKey(1))
+            draft_params = init_params(draft_lm, 1)
         drafter = make_drafter(
             args.draft,
             lm=draft_lm,
@@ -354,6 +365,9 @@ def main():
             f"wrote {len(eng.tracer.events())} trace events -> {args.trace_out} "
             "(open in chrome://tracing or ui.perfetto.dev)"
         )
+    n_failed = sum(r.status == "failed" for r in results)
+    if n_failed:
+        raise SystemExit(f"{n_failed} of {len(results)} requests failed")
 
 
 if __name__ == "__main__":

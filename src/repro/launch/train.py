@@ -18,6 +18,7 @@ import jax
 from repro.configs import ParallelConfig, TrainConfig, get_config
 from repro.core.schedule import Order
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import build_model
 from repro.train.fault_tolerance import FailureInjector
@@ -34,7 +35,7 @@ def parse_mesh(s: str):
     return make_local_mesh(*parts)
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -74,9 +75,10 @@ def main():
                          "(step time/throughput/loss/grad-norm series)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write step/checkpoint spans as Chrome-trace JSON")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO, format="%(asctime)s %(message)s")
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -116,8 +118,7 @@ def main():
     print(
         f"done: final_step={res.final_step} resumed_from={res.resumed_from} "
         f"first_loss={res.losses[0] if res.losses else None} "
-        f"last_loss={res.losses[-1] if res.losses else None} "
-        f"interrupted={res.interrupted}"
+        f"last_loss={res.losses[-1] if res.losses else None}"
     )
     if args.metrics_out and res.registry is not None:
         from repro.obs import write_metrics_jsonl

@@ -168,6 +168,8 @@ def attn_decode(
 def _paged_write(cfg: ModelConfig, cache: dict, k, v, starts, q_lens) -> dict:
     """Chunked write-at-offset into a paged cache — THE paged write path.
 
+    Pages are laid out (n_pages, Hkv, page, hd) (int8 scales (n_pages, Hkv,
+    page)), so a token lands at ``[phys, :, offset]``.
     k/v: (B, C, Hkv, hd) chunk values; row b's positions ``starts[b] + t``
     for ``t < q_lens[b]`` are written through the block table (logical page
     ``pos // page``, offset ``pos % page``). Invalid chunk rows (``t >=
@@ -178,7 +180,7 @@ def _paged_write(cfg: ModelConfig, cache: dict, k, v, starts, q_lens) -> dict:
     """
     b, c = k.shape[:2]
     bt = cache["block_table"]
-    page = cache["k_pages"].shape[1]
+    page = cache["k_pages"].shape[2]
     capacity = bt.shape[1] * page
     tq = jnp.arange(c, dtype=jnp.int32)[None, :]
     pos = starts[:, None] + tq                             # (B, C)
@@ -193,10 +195,12 @@ def _paged_write(cfg: ModelConfig, cache: dict, k, v, starts, q_lens) -> dict:
     for name, val in (("k_pages", k), ("v_pages", v)):
         if cfg.kv_cache_dtype == "int8":
             qv, sc = _quantize_kv(val)                     # (B,C,H,hd),(B,C,H)
-            out[name] = out[name].at[phys, offset].set(qv)
-            out[name + "_scale"] = out[name + "_scale"].at[phys, offset].set(sc)
+            out[name] = out[name].at[phys, :, offset].set(qv)
+            out[name + "_scale"] = out[name + "_scale"].at[phys, :, offset].set(sc)
         else:
-            out[name] = out[name].at[phys, offset].set(val.astype(out[name].dtype))
+            out[name] = out[name].at[phys, :, offset].set(
+                val.astype(out[name].dtype)
+            )
     return out
 
 
@@ -209,7 +213,7 @@ def _attn_decode_paged(cfg: ModelConfig, cache: dict, q, k, v):
     b, c = q.shape[:2]
     lens = cache["len"]  # (B,) tokens already cached (chunk positions follow)
     bt = cache["block_table"]
-    page = cache["k_pages"].shape[1]
+    page = cache["k_pages"].shape[2]
     capacity = bt.shape[1] * page
     q_lens = cache.get("q_len")
     if q_lens is None:
@@ -275,7 +279,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, dtype=None) -> dic
     kv_cache_dtype='int8' stores quantized values + per-vector scales.
 
     ``cfg.kv_layout == 'paged'`` switches to a page-pool layout: k/v pages
-    (n_pages, page, Hkv, hd) plus a per-row ``block_table`` (B, n_blocks)
+    (n_pages, Hkv, page, hd) plus a per-row ``block_table`` (B, n_blocks)
     initialized to the identity mapping (row i owns pages [i*n, (i+1)*n)),
     and per-row ``len`` (B,). A serving pool (repro.serve.kv_pool) re-maps
     block tables as sequences join and leave the running batch.
@@ -287,7 +291,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, dtype=None) -> dic
                 "archs keep the ring-buffer layout (kv_layout='contiguous')"
             )
         page, bpr = page_geometry(cfg, max_len)
-        shape = (batch * bpr, page, cfg.n_kv_heads, cfg.hd)
+        shape = (batch * bpr, cfg.n_kv_heads, page, cfg.hd)
         cache = {
             "len": jnp.zeros((batch,), jnp.int32),
             "block_table": jnp.arange(batch * bpr, dtype=jnp.int32).reshape(
@@ -364,7 +368,7 @@ def fill_cache(cfg: ModelConfig, cache: dict, k: jax.Array, v: jax.Array) -> dic
 
 def _fill_cache_paged(cfg: ModelConfig, cache: dict, k: jax.Array, v: jax.Array) -> dict:
     b, s = k.shape[:2]
-    page = cache["k_pages"].shape[1]
+    page = cache["k_pages"].shape[2]
     capacity = cache["block_table"].shape[1] * page
     if s > capacity:
         k, v = k[:, -capacity:], v[:, -capacity:]

@@ -57,7 +57,7 @@ from repro.models.model import LM, build_model
 from repro.obs import LLCSampler, Registry, Tracer
 from repro.obs.llc import DEFAULT_CAPACITY_BYTES
 from repro.serve.adapt import OrderAdaptController
-from repro.serve.faults import FaultPlan
+from repro.serve.faults import FaultPlan, StepFault
 from repro.serve.kv_pool import (
     AdmissionError,
     PagedKVPool,
@@ -326,7 +326,7 @@ class ServeEngine:
         all-slots worst case — the oversubscription knob that makes real
         (non-injected) pool pressure reachable. ``faults`` attaches a
         deterministic ``serve.faults.FaultPlan`` driving the no-op injection
-        hooks; one transient device-step failure per step is retried once
+        hooks; one injected ``StepFault`` per step is retried once
         before the step's rows fail.
 
         Tiered KV memory (DESIGN.md §13): ``host_pages > 0`` backs the
@@ -436,7 +436,6 @@ class ServeEngine:
         self._prefill = jax.jit(lambda p, b: lm.prefill(p, b, max_len))
         self._decode = jax.jit(lm.decode_step)
         self._mixed_step = None       # single jitted ragged step (continuous)
-        self._step_widths: set[int] = set()
 
         # ---- telemetry (repro.obs) ----
         self.obs = registry if registry is not None else Registry()
@@ -804,20 +803,20 @@ class ServeEngine:
                 ).astype(jnp.int32)
                 return toks, {name: caches[name] for name in pages}
 
-            self._mixed_step = jax.jit(step)
+            # The pool pages are donated: the step writes them in place, so
+            # the old and new pool are never both live (at deepseek-7b
+            # widths that is 1 GiB of a 16 GiB chip). The pool adopts the
+            # returned pages right after every dispatch.
+            self._mixed_step = jax.jit(step, donate_argnums=(2,))
         return self._mixed_step
 
     def compiled_step_count(self) -> int:
         """Number of compiled variants of the continuous mixed step (the
         compile-counter regression surface: O(1) — at most two widths — for
-        any stream of prompt lengths). Reads the jit cache itself when the
-        runtime exposes it; the engine-tracked width set is the fallback."""
+        any stream of prompt lengths), read from the jit cache itself."""
         if self._mixed_step is None:
             return 0
-        counter = getattr(self._mixed_step, "_cache_size", None)
-        if counter is not None:
-            return int(counter())
-        return len(self._step_widths)  # pragma: no cover
+        return int(self._mixed_step._cache_size())
 
     def _generate_continuous(
         self, requests: Sequence[Request]
@@ -1202,7 +1201,6 @@ class ServeEngine:
                 self._m_budget.set(planned / sched.token_budget)
 
                 width = 1 if all(it.q_len == 1 for it in plan) else self._chunk
-                self._step_widths.add(width)
                 tokens = np.full((n_slots, width), self.eos, np.int32)
                 qlens = np.zeros((n_slots,), np.int32)
                 # Per-row first sample index for the step's key ladder
@@ -1233,19 +1231,25 @@ class ServeEngine:
                 # dispatch itself is async). The step is functional (pages
                 # come back as fresh arrays; the pool adopts them only on
                 # success), so a failed dispatch leaves no partial state and
-                # a retry re-runs the identical computation: one transient
-                # failure is retried once, a second failure fails the
-                # step's rows cleanly and the engine moves on.
+                # a retry re-runs the identical computation: one injected
+                # ``StepFault`` is retried once, a second fails the step's
+                # rows cleanly and the engine moves on. Any other error
+                # (a compile refusal, an out-of-memory) propagates.
                 # Suspended rows keep their logical length host-side for the
                 # resume, but the step operand sees 0: their block-table row
                 # is dummied out, and a len>0 row over dummy pages is a
                 # shape the kernels never needed to define.
-                lens_op = pool.lens
+                # Every host array goes in as a snapshot: the CPU backend may
+                # alias numpy arguments zero-copy, and the step's page writes
+                # can still be reading them after ``toks`` is ready — while
+                # the host already advances lens/tables/temps in place.
+                lens_op = pool.lens.copy()
                 if tiered and pool.suspended_slots():
-                    lens_op = pool.lens.copy()
                     lens_op[pool.suspended_slots()] = 0
 
                 def dispatch():
+                    # The injected fault fires before the step is called,
+                    # so a retry never re-sends already donated pages.
                     if self.faults is not None:
                         self.faults.raise_if("device.step")
                     with self._mesh_ctx():
@@ -1253,7 +1257,7 @@ class ServeEngine:
                             self.params,
                             jnp.asarray(tokens),
                             pool.pages,
-                            pool.block_tables,
+                            pool.block_tables.copy(),
                             lens_op,
                             qlens,
                             np.int32(
@@ -1261,8 +1265,8 @@ class ServeEngine:
                                     pool.blocks_per_seq
                                 )
                             ),
-                            temps,
-                            seeds,
+                            temps.copy(),
+                            seeds.copy(),
                             bases,
                         )
                     if tiered and pool.fetch_backlog():
@@ -1285,12 +1289,12 @@ class ServeEngine:
                 ):
                     try:
                         toks, pages = dispatch()
-                    except Exception:
+                    except StepFault:
                         self._m_retries.inc()
                         tr.instant("serve.step_retry", step=step)
                         try:
                             toks, pages = dispatch()
-                        except Exception:
+                        except StepFault:
                             for it in plan:
                                 if sched.slots[it.slot] is not None:
                                     finish(it.slot, "failed")

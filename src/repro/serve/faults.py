@@ -56,7 +56,8 @@ FAULT_SITES = (
 
 
 class StepFault(RuntimeError):
-    """The injected (or real, wrapped) device-step failure type."""
+    """The injected device-step failure: the only error the engine's step
+    retry catches; every other error out of the step propagates."""
 
 
 @dataclasses.dataclass

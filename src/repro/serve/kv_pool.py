@@ -237,7 +237,9 @@ class PagedKVPool:
         self.alloc = PagePool(n_pages + 1, faults=faults)  # +1 dummy page 0
         self.faults = faults
 
-        shape = (n_layers, self.alloc.n_pages, self.page, cfg.n_kv_heads, cfg.hd)
+        # (L, n_pages, Hkv, page, hd): a page is one contiguous block, and
+        # its per-head (page, hd) tiles are what the paged kernel DMAs.
+        shape = (n_layers, self.alloc.n_pages, cfg.n_kv_heads, self.page, cfg.hd)
         self.pages: dict[str, jax.Array] = {}
         if cfg.kv_cache_dtype == "int8":
             for name in ("k_pages", "v_pages"):

@@ -270,12 +270,15 @@ class ModelDrafter(Drafter):
                 pool.ensure_writable(slot, len(seg))
                 tokens[slot, : len(seg)] = seg
                 qlens[slot] = len(seg)
+            # Snapshots of the host tables (see the engine's dispatch): the
+            # step may still read its arguments after ``toks`` is ready,
+            # and ``advance`` below mutates ``pool.lens`` in place.
             toks, pages = step_fn(
                 self.params,
                 jnp.asarray(tokens),
                 pool.pages,
-                pool.block_tables,
-                pool.lens,
+                pool.block_tables.copy(),
+                pool.lens.copy(),
                 qlens,
             )
             pool.update_pages(pages)

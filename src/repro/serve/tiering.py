@@ -90,8 +90,9 @@ class HostPageStore:
     """Bounded host-memory store of spilled page rows.
 
     A row is one physical page across every pool leaf — ``{leaf name ->
-    (L, page, ...) ndarray}`` — so int8 pools mirror their payloads and
-    float32 scale planes together. Handles are opaque monotonically
+    (L, ...) ndarray}``, the leaf indexed at that page — so int8 pools
+    mirror their payloads and float32 scale planes together. Handles are
+    opaque monotonically
     increasing ints; capacity is counted in pages (rows), matching the
     device pool's accounting unit.
     """
@@ -328,7 +329,7 @@ class TieredPagePool(PagedKVPool):
         stack = {}
         nbytes = 0
         for name in rows[0]:
-            h = np.stack([r[name] for r in rows], axis=1)  # (L, k, page, ...)
+            h = np.stack([r[name] for r in rows], axis=1)  # (L, k, ...)
             stack[name] = jax.device_put(h)
             nbytes += h.nbytes
         sus.chunks.append((pgs, stack))

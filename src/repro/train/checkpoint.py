@@ -108,10 +108,9 @@ def restore_pytree(template, directory: str, step: Optional[int] = None, *, shar
         info = meta["keys"][key]
         if info["dtype"] == "bfloat16":
             arr = arr.view(jnp.bfloat16)
-        val = jnp.asarray(arr)
-        if sh is not None:
-            val = jax.device_put(val, sh)
-        leaves.append(val)
+        # Straight from host to the target sharding: each device receives
+        # only its shard, never a whole leaf first.
+        leaves.append(jnp.asarray(arr) if sh is None else jax.device_put(arr, sh))
     return jax.tree_util.tree_unflatten(treedef, leaves), step
 
 

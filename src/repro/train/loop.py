@@ -21,7 +21,7 @@ from repro.models.model import LM
 from repro.obs import Registry, Tracer
 from repro.train.checkpoint import CheckpointManager
 from repro.train.fault_tolerance import FailureInjector, StepTimeout, Watchdog
-from repro.train.step import make_train_state, make_train_step, shard_state
+from repro.train.step import init_train_state, make_train_step, state_shardings
 
 log = logging.getLogger(__name__)
 
@@ -33,7 +33,6 @@ class TrainResult:
     final_step: int
     losses: list
     resumed_from: Optional[int]
-    interrupted: bool = False
     registry: Optional[Registry] = None   # step metrics (repro.obs)
     tracer: Optional[Tracer] = None       # step/checkpoint spans
 
@@ -87,14 +86,17 @@ def run_training(
     g_tput = obs.gauge("train.throughput_tokens_per_s")
 
     with jax.set_mesh(mesh):
-        state = make_train_state(lm, tcfg, jax.random.PRNGKey(tcfg.seed))
+        state = init_train_state(
+            lm, tcfg, pcfg, mesh, jax.random.PRNGKey(tcfg.seed)
+        )
         resumed_from = None
         if ckpt.latest_step() is not None:
             with tr.span("train.restore"):
-                state, resumed = ckpt.restore_latest(state)
+                state, resumed = ckpt.restore_latest(
+                    state, shardings=state_shardings(state, pcfg, mesh)
+                )
             resumed_from = resumed
             log.info("resumed from step %d", resumed)
-        state = shard_state(state, pcfg, mesh)
         start = resumed_from + 1 if resumed_from is not None else 0
 
         if make_batch is None:
@@ -109,8 +111,11 @@ def run_training(
         with tr.span("train.compile"):
             compiled = compile_step(state, batch0)
 
+        def save_final(i: int) -> None:
+            with tr.span("train.checkpoint", step=max(i - 1, 0), final=True):
+                ckpt.save(state, max(i - 1, 0), blocking=True)
+
         losses = []
-        interrupted = False
         t0 = time.time()
         i = start
         while i < steps:
@@ -135,10 +140,12 @@ def run_training(
                 m_retries.inc()
                 continue  # straggler mitigation: redo the step
             except RuntimeError as e:
-                log.error("step %d failed: %s — checkpoint + stop", i, e)
+                # Checkpoint the last completed step, then fail the run:
+                # the caller (and the launcher's exit code) must see it.
+                log.error("step %d failed: %s — checkpoint + re-raise", i, e)
                 tr.instant("train.failure", step=i)
-                interrupted = True
-                break
+                save_final(i)
+                raise
             dt_step = time.perf_counter() - t_step
             n_tok = _batch_tokens(batch)
             m_steps.inc()
@@ -162,13 +169,11 @@ def run_training(
                     ckpt.save(state, i)
             i += 1
 
-        with tr.span("train.checkpoint", step=max(i - 1, 0), final=True):
-            ckpt.save(state, max(i - 1, 0), blocking=True)
+        save_final(i)
         return TrainResult(
             final_step=i - 1,
             losses=losses,
             resumed_from=resumed_from,
-            interrupted=interrupted,
             registry=obs,
             tracer=tr,
         )

@@ -24,7 +24,13 @@ from repro.dist import sharding as shd
 from repro.models.model import LM
 from repro.train.optimizer import OptState, make_optimizer
 
-__all__ = ["TrainState", "make_train_state", "make_train_step", "make_serve_steps"]
+__all__ = [
+    "TrainState",
+    "make_train_state",
+    "init_train_state",
+    "make_train_step",
+    "make_serve_steps",
+]
 
 
 TrainState = dict  # {"params": pytree, "opt": OptState}
@@ -36,11 +42,16 @@ def make_train_state(lm: LM, tcfg: TrainConfig, key) -> TrainState:
     return {"params": params, "opt": opt_init(params)}
 
 
-def shard_state(state: TrainState, pcfg: ParallelConfig, mesh: Mesh) -> TrainState:
-    """Place a (host/replicated) state onto its target shardings. jit with
-    in_shardings does not reshard committed arrays — call this once after
-    init/restore."""
-    return jax.device_put(state, state_shardings(state, pcfg, mesh))
+def init_train_state(
+    lm: LM, tcfg: TrainConfig, pcfg: ParallelConfig, mesh: Mesh, key
+) -> TrainState:
+    """Build the train state under jit, straight onto its target shardings:
+    each device materialises only its own shard, and no f32 draw of a
+    stacked parameter leaf ever exists whole (at 7B widths one such leaf
+    alone is 5 GiB)."""
+    init = lambda k: make_train_state(lm, tcfg, k)
+    shardings = state_shardings(jax.eval_shape(init, key), pcfg, mesh)
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def state_shardings(state, pcfg: ParallelConfig, mesh: Mesh):

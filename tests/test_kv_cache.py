@@ -61,14 +61,14 @@ def _paged_problem(seed=0, b=3, hq=8, hkv=2, d=16, page=8, nb=4):
     """Random pool + shuffled block table + ragged lens + contiguous oracle."""
     rng = np.random.default_rng(seed)
     n_pages = b * nb + 1
-    kp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, d)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, d)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(n_pages, hkv, page, d)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(n_pages, hkv, page, d)).astype(np.float32))
     q = jnp.asarray(rng.normal(size=(b, 1, hq, d)).astype(np.float32))
     perm = rng.permutation(np.arange(1, n_pages))[: b * nb].reshape(b, nb)
     bt = jnp.asarray(perm.astype(np.int32))
     lens = jnp.asarray(np.array([5, 17, nb * page], np.int32))  # ragged
-    kc = kp[bt].reshape(b, nb * page, hkv, d)
-    vc = vp[bt].reshape(b, nb * page, hkv, d)
+    kc = kp[bt].transpose(0, 1, 3, 2, 4).reshape(b, nb * page, hkv, d)
+    vc = vp[bt].transpose(0, 1, 3, 2, 4).reshape(b, nb * page, hkv, d)
     return q, kp, vp, bt, lens, kc, vc
 
 
@@ -105,14 +105,19 @@ def test_paged_decode_free_slot_rows_are_zero():
 def test_paged_init_and_fill():
     cfg = get_config("deepseek-7b").reduced().with_(kv_layout="paged", page_size=8)
     cache = init_cache(cfg, batch=2, max_len=20)  # 3 pages per row
-    assert cache["k_pages"].shape == (6, 8, cfg.n_kv_heads, cfg.hd)
+    assert cache["k_pages"].shape == (6, cfg.n_kv_heads, 8, cfg.hd)
     np.testing.assert_array_equal(
         np.asarray(cache["block_table"]), np.arange(6).reshape(2, 3)
     )
     k = jax.random.normal(jax.random.PRNGKey(1), (2, 13, cfg.n_kv_heads, cfg.hd))
     cache = fill_cache(cfg, cache, k, k)
     np.testing.assert_array_equal(np.asarray(cache["len"]), [13, 13])
-    got = np.asarray(cache["k_pages"]).reshape(2, 24, cfg.n_kv_heads, cfg.hd)
+    got = (
+        np.asarray(cache["k_pages"])
+        .reshape(2, 3, cfg.n_kv_heads, 8, cfg.hd)
+        .transpose(0, 1, 3, 2, 4)
+        .reshape(2, 24, cfg.n_kv_heads, cfg.hd)
+    )
     np.testing.assert_allclose(got[:, :13], np.asarray(k), rtol=1e-6)
     assert np.abs(got[:, 13:]).max() == 0.0  # tail pages zero-padded
 

@@ -33,7 +33,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config, TrainConfig, ParallelConfig
         from repro.models import build_model
-        from repro.train.step import make_train_state, make_train_step, shard_state
+        from repro.train.step import init_train_state, make_train_step
         from repro.launch.mesh import make_local_mesh
 
         cfg = get_config("deepseek-7b").reduced()
@@ -46,8 +46,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
             mesh = make_local_mesh(d, m)
             pcfg = ParallelConfig(fsdp_axes=("data",), data_axes=("data",), microbatches=2)
             with jax.set_mesh(mesh):
-                state = make_train_state(lm, tcfg, jax.random.PRNGKey(0))
-                state = shard_state(state, pcfg, mesh)
+                state = init_train_state(lm, tcfg, pcfg, mesh, jax.random.PRNGKey(0))
                 step, compile_step = make_train_step(lm, tcfg, pcfg, mesh)
                 compiled = compile_step(state, batch)
                 state, metrics = compiled(state, batch)
@@ -56,6 +55,41 @@ def test_sharded_train_step_runs_and_matches_single_device():
         a, b = losses[(1, 1)], losses[(4, 2)]
         assert abs(a - b) < 5e-3, losses
         print("OK", losses)
+        """
+    )
+    assert "OK" in out
+
+
+def test_sharded_train_step_runs_pallas_kernels_per_shard():
+    """GSPMD cannot partition a Pallas kernel: under a mesh the attention
+    kernels run per batch shard inside a shard_map. The sharded step with
+    the (interpret-mode) kernels matches the single-device xla step."""
+    out = run_py(
+        """
+        import jax, numpy as np
+        from repro.configs import get_config, TrainConfig, ParallelConfig
+        from repro.models import build_model
+        from repro.train.step import init_train_state, make_train_step
+        from repro.launch.mesh import make_local_mesh
+
+        cfg = get_config("deepseek-7b").reduced()
+        tcfg = TrainConfig(lr=1e-3, warmup_steps=0)
+        batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 64), 0, cfg.vocab)}
+
+        out = {}
+        for impl, (d, m) in [("xla", (1, 1)), ("pallas_interpret", (4, 2))]:
+            lm = build_model(cfg.with_(attn_impl=impl))
+            mesh = make_local_mesh(d, m)
+            pcfg = ParallelConfig(fsdp_axes=("data",), data_axes=("data",))
+            with jax.set_mesh(mesh):
+                state = init_train_state(lm, tcfg, pcfg, mesh, jax.random.PRNGKey(0))
+                _, compile_step = make_train_step(lm, tcfg, pcfg, mesh)
+                compiled = compile_step(state, batch)
+                state, m0 = compiled(state, batch)
+                state, m1 = compiled(state, batch)
+                out[impl] = [float(m0["loss"]), float(m0["grad_norm"]), float(m1["loss"])]
+        np.testing.assert_allclose(out["pallas_interpret"], out["xla"], rtol=1e-4)
+        print("OK", out)
         """
     )
     assert "OK" in out
@@ -104,7 +138,7 @@ def test_elastic_remesh_restore():
         from repro.configs import get_config, TrainConfig, ParallelConfig
         from repro.models import build_model
         from repro.dist import sharding as shd
-        from repro.train.step import make_train_state, make_train_step, state_shardings, shard_state
+        from repro.train.step import init_train_state, make_train_state, make_train_step, state_shardings
         from repro.train.checkpoint import CheckpointManager
         from repro.train.fault_tolerance import elastic_remesh, usable_mesh_shape
         from repro.launch.mesh import make_local_mesh
@@ -121,8 +155,7 @@ def test_elastic_remesh_restore():
             mesh8 = make_local_mesh(4, 2)
             pcfg = ParallelConfig(fsdp_axes=("data",), data_axes=("data",))
             with jax.set_mesh(mesh8):
-                state = make_train_state(lm, tcfg, jax.random.PRNGKey(0))
-                state = shard_state(state, pcfg, mesh8)
+                state = init_train_state(lm, tcfg, pcfg, mesh8, jax.random.PRNGKey(0))
                 step, compile_step = make_train_step(lm, tcfg, pcfg, mesh8)
                 state, m1 = compile_step(state, batch)(state, batch)
             ck = CheckpointManager(d, keep=2)

@@ -48,15 +48,15 @@ def deepseek_lm():
 def _ragged_problem(seed=0, b=3, hq=8, hkv=2, d=16, page=8, nb=4, c=5):
     rng = np.random.default_rng(seed)
     n_pages = b * nb + 1
-    kp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, d)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(n_pages, page, hkv, d)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(size=(n_pages, hkv, page, d)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=(n_pages, hkv, page, d)).astype(np.float32))
     perm = rng.permutation(np.arange(1, n_pages))[: b * nb].reshape(b, nb)
     bt = jnp.asarray(perm, jnp.int32)  # shuffled block tables
     q = jnp.asarray(rng.normal(size=(b, c, hq, d)).astype(np.float32))
     lens = jnp.asarray([7, 20, nb * page], jnp.int32)   # total valid incl chunk
     qls = jnp.asarray([1, c, 3], jnp.int32)             # ragged chunk rows
-    kc = kp[bt].reshape(b, nb * page, hkv, d)
-    vc = vp[bt].reshape(b, nb * page, hkv, d)
+    kc = kp[bt].transpose(0, 1, 3, 2, 4).reshape(b, nb * page, hkv, d)
+    vc = vp[bt].transpose(0, 1, 3, 2, 4).reshape(b, nb * page, hkv, d)
     return q, kp, vp, bt, lens, qls, kc, vc
 
 

@@ -48,18 +48,21 @@ def test_loss_decreases_over_training(setup, tmp_path):
 def test_crash_checkpoint_resume(setup, tmp_path):
     cfg, lm, mesh, batches = setup
     inj = FailureInjector(crash_at=(12,))
-    res1 = run_training(
-        lm, _tcfg(tmp_path, 20), ParallelConfig(), mesh,
-        make_batch=lambda s: batches[s], injector=inj, log_every=0,
-    )
-    assert res1.interrupted and res1.final_step < 20
+    # The failure propagates after the last completed step is checkpointed.
+    with pytest.raises(RuntimeError, match="injected"):
+        run_training(
+            lm, _tcfg(tmp_path, 20), ParallelConfig(), mesh,
+            make_batch=lambda s: batches[s], injector=inj, log_every=0,
+        )
+    from repro.train.checkpoint import latest_step
+    assert latest_step(str(tmp_path)) == 11
     # resume picks up from the last checkpoint and finishes
     res2 = run_training(
         lm, _tcfg(tmp_path, 20), ParallelConfig(), mesh,
         make_batch=lambda s: batches[s], log_every=0,
     )
-    assert res2.resumed_from is not None and res2.resumed_from >= 9
-    assert res2.final_step == 19 and not res2.interrupted
+    assert res2.resumed_from == 11
+    assert res2.final_step == 19
 
 
 def test_straggler_watchdog_retries(setup, tmp_path):

@@ -1,0 +1,200 @@
+"""Compile-only checks of the main path for a described TPU v5e chip.
+
+The TPU compiler is installed even where no chip is attached; compiling
+against a described ``v5e:2x2`` topology raises what the chip's compiler
+would raise (tiling rules interpret mode never checks, a Mosaic kernel
+GSPMD cannot partition); ``memory_analysis`` shows whether a program fits
+the chip. Nothing runs, so these say nothing about results or times.
+Shapes are deepseek-7b's published widths as the chip smoke serves them:
+4 slots, ``max_len`` 512, 128-row pages (17 pool pages with the dummy).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and the test workers import every file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_attention import flash_attention_bwd, flash_attention_fwd
+from repro.kernels import ops
+from repro.kernels.flash_decode import flash_decode_fwd, paged_flash_decode_fwd
+from repro.models import build_model
+from repro.serve import ServeEngine
+
+GiB = 2**30
+HBM_BYTES = 15.748 * GiB  # the allocator limit a v5e chip reports (16 GiB HBM)
+SLOTS, MAX_LEN, PAGE = 4, 512, 128
+N_PAGES = SLOTS * MAX_LEN // PAGE + 1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # A TPU compile written to the persistent cache cannot be read back
+    # without a chip; keep the cache off while this module compiles.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    from jax.experimental import topologies
+
+    try:
+        yield topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    # attn_impl would resolve to "xla" on this CPU host; the chip runs pallas.
+    return get_config("deepseek-7b").with_(attn_impl="pallas")
+
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _in_hbm(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes
+        + m.output_size_in_bytes
+        + m.temp_size_in_bytes
+        - m.alias_size_in_bytes
+    )
+
+
+@pytest.mark.parametrize("c", [1, MAX_LEN], ids=["decode", "chunk"])
+def test_paged_decode_kernel_compiles(one_chip, cfg, c):
+    """The ragged paged kernel at C = 1 and C = the prefill chunk."""
+    s = lambda shape, dt=jnp.int32: _spec(one_chip, shape, dt)
+    pool = s((N_PAGES, cfg.n_kv_heads, PAGE, cfg.hd), jnp.bfloat16)
+    fn = jax.jit(
+        lambda q, k, v, lens, bt, qlens: paged_flash_decode_fwd(
+            q, k, v, lens, bt, q_lens=qlens, order="sawtooth"
+        )
+    )
+    compiled = fn.lower(
+        s((SLOTS, c, cfg.n_heads, cfg.hd), jnp.bfloat16),
+        pool,
+        pool,
+        s((SLOTS,)),
+        s((SLOTS, MAX_LEN // PAGE)),
+        s((SLOTS,)),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_contiguous_decode_kernel_compiles(one_chip, cfg):
+    """The static path's decode kernel, whose (B, 1, S) mask keeps its
+    block legal for a batch of more than one row."""
+    cache = _spec(one_chip, (SLOTS, 2048, cfg.n_kv_heads, cfg.hd), jnp.bfloat16)
+    fn = jax.jit(lambda q, k, v, lens: flash_decode_fwd(q, k, v, lens))
+    compiled = fn.lower(
+        _spec(one_chip, (SLOTS, 1, cfg.n_heads, cfg.hd), jnp.bfloat16),
+        cache,
+        cache,
+        _spec(one_chip, (SLOTS,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _attn_specs(one_chip, cfg, seq=2048):
+    return _spec(one_chip, (1, seq, cfg.n_heads, cfg.hd), jnp.bfloat16)
+
+
+def test_flash_forward_compiles_at_512_tiles(one_chip, cfg):
+    x = _attn_specs(one_chip, cfg)
+    fn = jax.jit(
+        lambda q, k, v: flash_attention_fwd(
+            q, k, v, causal=True, q_block=512, kv_block=512, return_lse=True
+        )
+    )
+    assert "tpu_custom_call" in fn.lower(x, x, x).compile().as_text()
+
+
+def test_flash_fused_backward_compiles_at_512_tiles(one_chip, cfg):
+    x = _attn_specs(one_chip, cfg)
+    lse = _spec(one_chip, (1, 2048, cfg.n_heads), jnp.float32)
+    fn = jax.jit(
+        lambda q, k, v, o, lse, do: flash_attention_bwd(
+            q, k, v, o, lse, do, causal=True, q_block=512, kv_block=512
+        )
+    )
+    compiled = fn.lower(x, x, x, x, lse, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_grad_compiles_on_four_chips(topo, cfg):
+    """GSPMD cannot partition a Mosaic kernel; under a mesh the attention
+    op must run the kernels per batch shard (FSDP training, 4x1 mesh)."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "model"))
+    x = jax.ShapeDtypeStruct(
+        (4, 2048, cfg.n_heads, cfg.hd), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("data")),
+    )
+
+    def loss(q, k, v):
+        o = ops.attention(q, k, v, causal=True, q_block=512, kv_block=512,
+                          impl="pallas")
+        return o.astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh):
+        grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        compiled = grad.lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_jitted_init_fits_one_chip(one_chip, cfg):
+    """Full-width random init under jit: the bf16 weights and nothing like
+    an f32 copy of a stacked leaf on top."""
+    lm = build_model(cfg)
+    key = _spec(one_chip, (2,), jnp.uint32)
+    compiled = jax.jit(lm.init).lower(key).compile()
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes > 12.5 * GiB  # 6.9 B bf16 parameters
+    assert m.temp_size_in_bytes < 0.1 * GiB
+    assert _in_hbm(compiled) < HBM_BYTES
+
+
+def test_serve_mixed_step_fits_one_chip(one_chip, cfg):
+    """The engine's ragged mixed step at chunk width: weights, the donated
+    page pool and the step's temporaries together within one chip."""
+    lm = build_model(cfg)
+    params = jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lm.init, jax.random.PRNGKey(0)),
+    )
+    eng = ServeEngine(
+        lm, None, batch_size=SLOTS, max_len=MAX_LEN, scheduler="continuous",
+        page_size=PAGE,
+    )
+    s = lambda shape, dt=jnp.int32: _spec(one_chip, shape, dt)
+    pages = s((cfg.n_layers, N_PAGES, cfg.n_kv_heads, PAGE, cfg.hd), jnp.bfloat16)
+    compiled = eng._mixed_step_fn().lower(
+        params,
+        s((SLOTS, MAX_LEN)),
+        {"k_pages": pages, "v_pages": pages},
+        s((SLOTS, MAX_LEN // PAGE)),
+        s((SLOTS,)),
+        s((SLOTS,)),
+        s(()),
+        s((SLOTS,), jnp.float32),
+        s((SLOTS,)),
+        s((SLOTS,)),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes > 0.9 * GiB
+    assert _in_hbm(compiled) < HBM_BYTES
