@@ -48,39 +48,34 @@ def _resolve(impl: Impl) -> str:
     return default_impl() if impl == "auto" else impl
 
 
-def _mesh_split(b: int, hkv: int):
-    """(batch axes, head axes) of the ambient mesh for a Pallas call, or
-    None outside a multi-device mesh.
+def _batch_axes(mesh, b: int):
+    """Mesh axes a Pallas call splits a batch of ``b`` rows over, or None
+    off a multi-device mesh (the call is then a plain one).
 
     GSPMD cannot partition a Mosaic kernel, so under a mesh each device
-    runs the kernel on its own slice inside a ``shard_map``: mesh axes go
-    to the batch while their product divides it, then to the kv heads;
-    an axis that divides neither leaves the operands replicated along it.
+    runs the kernel on its own batch slice inside a ``shard_map``: mesh
+    axes go to the batch while their product divides it. The operands
+    are replicated along every other axis (heads are never split here).
     """
-    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
         return None
-    batch, heads, nb, nh = [], [], 1, 1
+    axes, n = [], 1
     for name, size in mesh.shape.items():
-        if size == 1:
-            continue
-        if b % (nb * size) == 0:
-            batch.append(name)
-            nb *= size
-        elif hkv % (nh * size) == 0:
-            heads.append(name)
-            nh *= size
-    return tuple(batch) or None, tuple(heads) or None
+        if size > 1 and b % (n * size) == 0:
+            axes.append(name)
+            n *= size
+    return tuple(axes)
 
 
 def _per_shard(call, *args, out_ranks=(4,)):
-    """``call(*args)`` shard by shard on the ambient mesh (see
-    :func:`_mesh_split`); a plain call off-mesh. Operands and results are
+    """``call(*args)`` batch shard by batch shard on the ambient mesh (see
+    :func:`_batch_axes`); a plain call off-mesh. Operands and results are
     (B, S, H, D) arrays or (B, S, Hq) lse rows, told apart by rank."""
-    split = _mesh_split(args[0].shape[0], args[1].shape[2])
-    if split is None:
+    axes = _batch_axes(jax.sharding.get_abstract_mesh(), args[0].shape[0])
+    if axes is None:
         return call(*args)
-    spec = {4: P(split[0], None, split[1], None), 3: P(split[0], None, split[1])}
+    axes = axes or None  # no axis divides the batch: replicate everywhere
+    spec = {4: P(axes, None, None, None), 3: P(axes, None, None)}
     out = tuple(spec[r] for r in out_ranks)
     return jax.shard_map(
         call,

@@ -744,23 +744,34 @@ class ServeEngine:
     # prefill jit cache of the previous design (unbounded compilation
     # growth) is gone, as is the separate decode-only step.
 
+    def step_logits(self, params, tokens, pages, bt, lens, qlens, order_group):
+        """The mixed step's model half: ``(logits (B, C, V), new pages)``
+        for a ragged chunk ``tokens`` written at each row's ``lens`` offset
+        (``qlens`` valid positions per row). The compiled step samples
+        from exactly these logits.
+
+        ``order_group`` is the traced effective reversal-group scalar
+        (adapt.OrderAdaptController.effective_group): the traversal order
+        is step *data*, so the adaptation can switch it between steps
+        inside one compiled step.
+        """
+        caches = assemble_cache_view(
+            pages, bt, lens, self.lm.cfg.n_layers, qlens, order_group
+        )
+        logits, caches = self.lm.decode_step(params, tokens, caches)
+        return logits, {name: caches[name] for name in pages}
+
     def _mixed_step_fn(self):
         if self._mixed_step is None:
-            lm, base = self.lm, self.key
-            n_layers = lm.cfg.n_layers
+            base = self.key
 
             def step(
                 params, tokens, pages, bt, lens, qlens, order_group,
                 temps, seeds, bases,
             ):
-                # ``order_group`` is the traced effective reversal-group
-                # scalar (adapt.OrderAdaptController.effective_group): the
-                # traversal order is step *data*, so the adaptation can
-                # switch it between steps inside this one compiled step.
-                caches = assemble_cache_view(
-                    pages, bt, lens, n_layers, qlens, order_group
+                logits, new_pages = self.step_logits(
+                    params, tokens, pages, bt, lens, qlens, order_group
                 )
-                logits, caches = lm.decode_step(params, tokens, caches)
                 # EVERY chunk position is sampled — position p of row b uses
                 # the PRNG key for sample index ``bases[b] + p``, the exact
                 # key a sequence of q_len=1 steps would have used one by
@@ -801,7 +812,7 @@ class ServeEngine:
                 toks = jnp.where(
                     temps[:, None] > 0.0, sampled, greedy
                 ).astype(jnp.int32)
-                return toks, {name: caches[name] for name in pages}
+                return toks, new_pages
 
             # The pool pages are donated: the step writes them in place, so
             # the old and new pool are never both live (at deepseek-7b

@@ -142,9 +142,15 @@ def run_training(
             except RuntimeError as e:
                 # Checkpoint the last completed step, then fail the run:
                 # the caller (and the launcher's exit code) must see it.
+                # The step donates ``state``: after an error raised at or
+                # past the dispatch its buffers may be gone, so the save is
+                # best effort and never replaces the step's own error.
                 log.error("step %d failed: %s — checkpoint + re-raise", i, e)
                 tr.instant("train.failure", step=i)
-                save_final(i)
+                try:
+                    save_final(i)
+                except Exception:
+                    log.exception("checkpoint after the step %d failure failed", i)
                 raise
             dt_step = time.perf_counter() - t_step
             n_tok = _batch_tokens(batch)

@@ -60,12 +60,35 @@ def test_sharded_train_step_runs_and_matches_single_device():
     assert "OK" in out
 
 
-def test_sharded_train_step_runs_pallas_kernels_per_shard():
+@pytest.mark.parametrize(
+    "mesh_shape,b,want",
+    [
+        ((1, 1), 8, None),
+        ((4, 2), 8, ("data", "model")),
+        ((4, 2), 4, ("data",)),
+        ((4, 2), 2, ("model",)),
+        ((4, 2), 3, ()),
+    ],
+)
+def test_pallas_batch_axes(mesh_shape, b, want):
+    """Mesh axes go to the batch while their product divides it; the rest
+    replicate (an empty tuple: replicated on every axis)."""
+    from jax.sharding import AbstractMesh
+
+    from repro.kernels.ops import _batch_axes
+
+    assert _batch_axes(AbstractMesh(mesh_shape, ("data", "model")), b) == want
+
+
+@pytest.mark.parametrize("batch", [8, 4])
+def test_sharded_train_step_runs_pallas_kernels_per_shard(batch):
     """GSPMD cannot partition a Pallas kernel: under a mesh the attention
     kernels run per batch shard inside a shard_map. The sharded step with
-    the (interpret-mode) kernels matches the single-device xla step."""
+    the (interpret-mode) kernels matches the single-device xla step, both
+    with the batch split over the whole 4x2 mesh and with the ``model``
+    axis left replicated (batch 4)."""
     out = run_py(
-        """
+        f"""
         import jax, numpy as np
         from repro.configs import get_config, TrainConfig, ParallelConfig
         from repro.models import build_model
@@ -74,9 +97,9 @@ def test_sharded_train_step_runs_pallas_kernels_per_shard():
 
         cfg = get_config("deepseek-7b").reduced()
         tcfg = TrainConfig(lr=1e-3, warmup_steps=0)
-        batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (8, 64), 0, cfg.vocab)}
+        batch = {{"tokens": jax.random.randint(jax.random.PRNGKey(1), ({batch}, 64), 0, cfg.vocab)}}
 
-        out = {}
+        out = {{}}
         for impl, (d, m) in [("xla", (1, 1)), ("pallas_interpret", (4, 2))]:
             lm = build_model(cfg.with_(attn_impl=impl))
             mesh = make_local_mesh(d, m)

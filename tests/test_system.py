@@ -65,6 +65,27 @@ def test_crash_checkpoint_resume(setup, tmp_path):
     assert res2.final_step == 19
 
 
+def test_failed_checkpoint_after_a_crash_keeps_the_step_error(
+    setup, tmp_path, monkeypatch
+):
+    """After a device error the donated state may be unsaveable: the save's
+    own failure is logged, and the step's error is what propagates."""
+    from repro.train.checkpoint import CheckpointManager
+
+    cfg, lm, mesh, batches = setup
+
+    def broken_save(self, *a, **k):
+        raise OSError("state buffers were donated")
+
+    monkeypatch.setattr(CheckpointManager, "save", broken_save)
+    with pytest.raises(RuntimeError, match="injected"):
+        run_training(
+            lm, _tcfg(tmp_path, 4, checkpoint_every=0), ParallelConfig(), mesh,
+            make_batch=lambda s: batches[s],
+            injector=FailureInjector(crash_at=(2,)), log_every=0,
+        )
+
+
 def test_straggler_watchdog_retries(setup, tmp_path):
     cfg, lm, mesh, batches = setup
 
