@@ -302,6 +302,7 @@ def flash_attention_fwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
+        name="flash_attention_fwd",
     )(qf, kf, vf)
 
     out = outs[0].reshape(b, hkv, g, sq_p, dp)[:, :, :, :sq, :d]
@@ -550,6 +551,7 @@ def flash_attention_bwd(
         out_specs=pl.BlockSpec((1, q_block, LANES), row_map),
         out_shape=jax.ShapeDtypeStruct((b * hkv, g * sq_p, LANES), jnp.float32),
         **interp,
+        name="flash_attention_bwd_delta",
     )(of, dof)
 
     # ---- dQ: forward grid ----------------------------------------------------
@@ -574,6 +576,7 @@ def flash_attention_bwd(
         out_specs=pl.BlockSpec((1, q_block, dp), q_map3),
         out_shape=jax.ShapeDtypeStruct((b * hkv, g * sq_p, dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((q_block, dp), jnp.float32)],
+        name="flash_attention_bwd_dq",
         **interp,
         **compiler3,
     )(qf, kf, vf, dof, lse_f, delta_f)
@@ -609,6 +612,7 @@ def flash_attention_bwd(
             pltpu.VMEM((kv_block, dp), jnp.float32),
             pltpu.VMEM((kv_block, dp), jnp.float32),
         ],
+        name="flash_attention_bwd_dkv",
         **interp,
         **compiler3,
     )(qf, kf, vf, dof, lse_f, delta_f)

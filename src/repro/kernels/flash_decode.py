@@ -305,6 +305,7 @@ def _flash_decode_contiguous(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name="flash_decode_fwd",
     )(qf, kf, vf, mask)
 
     out = out.reshape(b, hkv, g_pad, dp)[:, :, :g, :d]
@@ -431,6 +432,7 @@ def paged_flash_decode_fwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name="paged_flash_decode_fwd",
     )(phys, visit, meta, qf, kf, vf)
 
     out = out[:, :cg, :d].reshape(b, hkv, c, g, d)

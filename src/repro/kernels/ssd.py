@@ -163,6 +163,7 @@ def ssd_fwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
+        name="ssd_fwd",
     )(xf, daf, dtf, b, c, init)
 
     y = y.reshape(bsz, h, sp, p).transpose(0, 2, 1, 3)[:, :s]

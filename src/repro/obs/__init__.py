@@ -8,8 +8,8 @@ schema checks (``benchmarks/check_metrics.py``), trace viewers, and —
 next — the online traversal-order adaptation that reads
 ``llc.modeled_miss_bytes`` (ROADMAP item 4).
 
-``span``/``instant`` are process-default-tracer conveniences; engines and
-the train loop carry their own instances so streams don't interleave.
+Engines and the train loop carry their own instances, so streams don't
+interleave.
 """
 
 from repro.obs.autotune import (
@@ -32,9 +32,8 @@ from repro.obs.metrics import (
     Gauge,
     Histogram,
     Registry,
-    default_registry,
 )
-from repro.obs.trace import SpanEvent, Tracer, default_tracer, instant, span
+from repro.obs.trace import SpanEvent, Tracer
 
 __all__ = [
     "SCHEMA_VERSION",
@@ -53,10 +52,6 @@ __all__ = [
     "Gauge",
     "Histogram",
     "Registry",
-    "default_registry",
     "SpanEvent",
     "Tracer",
-    "default_tracer",
-    "instant",
-    "span",
 ]

@@ -33,7 +33,6 @@ __all__ = [
     "Histogram",
     "Registry",
     "LATENCY_BUCKETS_S",
-    "default_registry",
 ]
 
 # Default histogram ladder for wall-clock seconds: 1e-4 .. 100 s, roughly
@@ -201,12 +200,3 @@ class Registry:
 
     def reset(self) -> None:
         self._metrics.clear()
-
-
-_default = Registry()
-
-
-def default_registry() -> Registry:
-    """The process-wide registry (components default to their own private
-    registries; this one backs the module-level convenience handles)."""
-    return _default

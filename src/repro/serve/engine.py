@@ -38,6 +38,7 @@ path.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import math
@@ -445,6 +446,7 @@ class ServeEngine:
         r = self.obs  # hot-loop handles resolved once (recording = attr add)
         self._m_tok_decode = r.counter("serve.step.tokens", kind="decode")
         self._m_tok_prefill = r.counter("serve.step.tokens", kind="prefill")
+        self._m_kv_tok = r.counter("serve.step.kv_tokens")
         self._m_generated = r.counter("serve.tokens.generated")
         self._m_steps_wide = r.counter("serve.steps", width="wide")
         self._m_steps_narrow = r.counter("serve.steps", width="narrow")
@@ -871,8 +873,15 @@ class ServeEngine:
         temps = np.zeros((n_slots,), np.float32)
         seeds = np.zeros((n_slots,), np.int32)
         counts = np.zeros((n_slots,), np.int32)
-        t0 = time.perf_counter()
+        t0_ns = time.perf_counter_ns()
+        t0 = t0_ns / 1e9
         first_t: dict[int, float] = {}
+        # A request's queue wait (``serve.queued``) starts at t0, or at the
+        # boundary that first reaches its arrival step (``ready_ns``).
+        arrivals = collections.deque(
+            sorted({r.arrival for r in requests if r.arrival > 0})
+        )
+        ready_ns: dict[int, int] = {}
 
         def resolve(r, tokens: list, status: str) -> None:
             # Terminal for ANY lifecycle outcome — every submitted request
@@ -1044,82 +1053,95 @@ class ServeEngine:
         while sched.has_work():
             t_iter = time.perf_counter()
             with tr.span("serve.step", step=step):
-                # ---- step-boundary lifecycle checks (DESIGN.md §12) ----
-                if self.faults is not None:
-                    self.faults.begin_step(step)
-                    for rid in self.faults.take_cancels():
-                        self._cancelled.add(int(rid))
-                if self._cancelled:
-                    hit = sched.drain_waiting(
-                        lambda r: r.rid in self._cancelled
-                    )
-                    for r in hit:
-                        resolve(r, resume.pop(id(r), []), "cancelled")
-                    for i in list(sched.active_slots()):
-                        if sched.slots[i].request.rid in self._cancelled:
-                            finish(i, "cancelled")
-                now_s = time.perf_counter() - t0
-                for r in sched.drain_waiting(
-                    lambda r: r.deadline_s is not None and now_s > r.deadline_s
-                ):
-                    resolve(r, resume.pop(id(r), []), "deadline")
-                for i in list(sched.active_slots()):
-                    r = sched.slots[i].request
-                    if r.deadline_s is not None and now_s > r.deadline_s:
-                        finish(i, "deadline")
+                with tr.span("serve.admit"):
+                    # Arrival steps reached at this boundary: the queue
+                    # wait of a request arriving there starts now.
+                    now_ns = time.perf_counter_ns()
+                    while arrivals and arrivals[0] <= step:
+                        ready_ns[arrivals.popleft()] = now_ns
 
-                # Tiered KV boundary work BEFORE admission: spilling down to
-                # the spill watermark is what un-pauses admission under the
-                # (higher) admit watermark — park cold work, keep admitting.
-                if tiered:
-                    tier_boundary()
-
-                # Admission: fill free slots with arrived requests while the
-                # pool can reserve their (sharing-reduced) worst case. The
-                # high watermark pauses admission under pool pressure so new
-                # work does not immediately thrash running work back out via
-                # preemption; with no active slots it never pauses (only
-                # retirements can lower occupancy — registered prefix pages
-                # legitimately outlive their donors).
-                paused = (
-                    pool.occupancy() >= self._watermark
-                    and bool(sched.active_slots())
-                )
-                self._m_admit_paused.set(float(paused))
-                while not paused and (slot := sched.free_slot()) is not None:
-                    req = sched.pop_admissible(step)
-                    if req is None:
-                        break
-                    restored = id(req) in resume
-                    ctx = (
-                        tr.span("serve.preempt_restore", rid=req.rid)
-                        if restored
-                        else contextlib.nullcontext()
-                    )
-                    with ctx:
-                        st = self._admit(
-                            req, slot, sched, pool, temps, seeds, counts,
-                            idx_of.get(id(req), 0), prior=resume.get(id(req)),
+                    # ---- step-boundary lifecycle checks (DESIGN.md §12) ----
+                    if self.faults is not None:
+                        self.faults.begin_step(step)
+                        for rid in self.faults.take_cancels():
+                            self._cancelled.add(int(rid))
+                    if self._cancelled:
+                        hit = sched.drain_waiting(
+                            lambda r: r.rid in self._cancelled
                         )
-                    if st is None:
-                        sched.requeue(req)  # no pages yet; retry after retirements
-                        self._m_req_requeued.inc()
-                        break
-                    resume.pop(id(req), None)
-                    self._m_req_admitted.inc()
-                    if restored and st.prompt is not None:
-                        n_re = int(len(st.prompt) - st.prompt_pos)
-                        tally["restore"] += n_re
-                        self._m_restore_tok.inc(n_re)
-                    if st.done:  # zero-limit request: emits nothing
-                        finish(slot)
+                        for r in hit:
+                            resolve(r, resume.pop(id(r), []), "cancelled")
+                        for i in list(sched.active_slots()):
+                            if sched.slots[i].request.rid in self._cancelled:
+                                finish(i, "cancelled")
+                    now_s = time.perf_counter() - t0
+                    for r in sched.drain_waiting(
+                        lambda r: r.deadline_s is not None and now_s > r.deadline_s
+                    ):
+                        resolve(r, resume.pop(id(r), []), "deadline")
+                    for i in list(sched.active_slots()):
+                        r = sched.slots[i].request
+                        if r.deadline_s is not None and now_s > r.deadline_s:
+                            finish(i, "deadline")
 
-                # Load shed AFTER admission drained what it could: the
-                # queue bound applies to arrived requests this boundary
-                # could not place, newest rejected first.
-                if self.max_queue is not None:
-                    for r in sched.shed_over(step, self.max_queue):
-                        resolve(r, resume.pop(id(r), []), "shed")
+                    # Tiered KV boundary work BEFORE admission: spilling down to
+                    # the spill watermark is what un-pauses admission under the
+                    # (higher) admit watermark — park cold work, keep admitting.
+                    if tiered:
+                        tier_boundary()
+
+                    # Admission: fill free slots with arrived requests while the
+                    # pool can reserve their (sharing-reduced) worst case. The
+                    # high watermark pauses admission under pool pressure so new
+                    # work does not immediately thrash running work back out via
+                    # preemption; with no active slots it never pauses (only
+                    # retirements can lower occupancy — registered prefix pages
+                    # legitimately outlive their donors).
+                    paused = (
+                        pool.occupancy() >= self._watermark
+                        and bool(sched.active_slots())
+                    )
+                    self._m_admit_paused.set(float(paused))
+                    while not paused and (slot := sched.free_slot()) is not None:
+                        req = sched.pop_admissible(step)
+                        if req is None:
+                            break
+                        restored = id(req) in resume
+                        ctx = (
+                            tr.span("serve.preempt_restore", rid=req.rid)
+                            if restored
+                            else contextlib.nullcontext()
+                        )
+                        with ctx:
+                            st = self._admit(
+                                req, slot, sched, pool, temps, seeds, counts,
+                                idx_of.get(id(req), 0), prior=resume.get(id(req)),
+                            )
+                        if st is None:
+                            sched.requeue(req)  # no pages yet; retry after retirements
+                            self._m_req_requeued.inc()
+                            break
+                        resume.pop(id(req), None)
+                        self._m_req_admitted.inc()
+                        if not restored:  # a restore is not a new wait
+                            start = ready_ns.get(req.arrival, t0_ns)
+                            tr.record(
+                                "serve.queued", start,
+                                time.perf_counter_ns() - start, rid=req.rid,
+                            )
+                        if restored and st.prompt is not None:
+                            n_re = int(len(st.prompt) - st.prompt_pos)
+                            tally["restore"] += n_re
+                            self._m_restore_tok.inc(n_re)
+                        if st.done:  # zero-limit request: emits nothing
+                            finish(slot)
+
+                    # Load shed AFTER admission drained what it could: the
+                    # queue bound applies to arrived requests this boundary
+                    # could not place, newest rejected first.
+                    if self.max_queue is not None:
+                        for r in sched.shed_over(step, self.max_queue):
+                            resolve(r, resume.pop(id(r), []), "shed")
 
                 # Speculative drafting (DESIGN.md §14) — ONCE per boundary,
                 # before the plan/pressure retry loop: a model drafter runs
@@ -1222,8 +1244,10 @@ class ServeEngine:
                 # old single-position step folded.
                 bases = counts.copy()
                 n_decode = n_prefill = 0
+                n_kv = 0  # keys the step's queries attend, over its rows
                 for it in plan:
                     st = sched.slots[it.slot]
+                    n_kv += int(pool.lens[it.slot]) + it.q_len
                     if it.is_prefill:
                         seg = st.prompt[st.prompt_pos : st.prompt_pos + it.q_len]
                         tokens[it.slot, : len(seg)] = seg
@@ -1263,7 +1287,7 @@ class ServeEngine:
                     # so a retry never re-sends already donated pages.
                     if self.faults is not None:
                         self.faults.raise_if("device.step")
-                    with self._mesh_ctx():
+                    with self._mesh_ctx(), tr.span("serve.dispatch"):
                         toks_dev, pages = step_fn(
                             self.params,
                             jnp.asarray(tokens),
@@ -1292,7 +1316,9 @@ class ServeEngine:
                                 pool.issue_fetches(
                                     i, self.prefetch_depth, overlapped=True
                                 )
-                    return np.asarray(toks_dev), pages
+                    with tr.span("serve.wait_tokens"):
+                        toks = np.asarray(toks_dev)
+                    return toks, pages
 
                 with tr.span(
                     "serve.device_step", width=width, rows=len(plan),
@@ -1311,101 +1337,103 @@ class ServeEngine:
                                     finish(it.slot, "failed")
                             step += 1
                             continue
-                pool.update_pages(pages)
-                cc = self.compiled_step_count()
-                if cc > last_cc:
-                    tr.instant("serve.compile", width=width, variants=cc)
-                    self._m_compiles.inc(cc - last_cc)
-                    last_cc = cc
-                step += 1
-                n_steps += 1
-                n_wide += width > 1
-                self._m_tok_decode.inc(n_decode)
-                self._m_tok_prefill.inc(n_prefill)
-                (self._m_steps_wide if width > 1 else self._m_steps_narrow).inc()
-                for it in plan:
-                    st = sched.slots[it.slot]
-                    pool.advance(it.slot, it.q_len)
-                    if it.is_prefill:
-                        st.prompt_pos += it.q_len
-                        if not it.finishes_prompt:
+                with tr.span("serve.commit"):
+                    pool.update_pages(pages)
+                    cc = self.compiled_step_count()
+                    if cc > last_cc:
+                        tr.instant("serve.compile", width=width, variants=cc)
+                        self._m_compiles.inc(cc - last_cc)
+                        last_cc = cc
+                    step += 1
+                    n_steps += 1
+                    n_wide += width > 1
+                    self._m_tok_decode.inc(n_decode)
+                    self._m_tok_prefill.inc(n_prefill)
+                    self._m_kv_tok.inc(n_kv)
+                    (self._m_steps_wide if width > 1 else self._m_steps_narrow).inc()
+                    for it in plan:
+                        st = sched.slots[it.slot]
+                        pool.advance(it.slot, it.q_len)
+                        if it.is_prefill:
+                            st.prompt_pos += it.q_len
+                            if not it.finishes_prompt:
+                                continue
+                            # Prompt complete: publish its frozen pages for future
+                            # admissions to adopt, then take the first sample.
+                            pool.register_prompt(it.slot, st.prompt)
+                        if it.n_draft == 0:
+                            tok = int(toks[it.slot, it.q_len - 1])
+                            if id(st.request) not in first_t:
+                                first_t[id(st.request)] = time.perf_counter()
+                            counts[it.slot] += 1
+                            cur[it.slot] = tok
+                            if st.record(tok):
+                                finish(it.slot)
                             continue
-                        # Prompt complete: publish its frozen pages for future
-                        # admissions to adopt, then take the first sample.
-                        pool.register_prompt(it.slot, st.prompt)
-                    if it.n_draft == 0:
-                        tok = int(toks[it.slot, it.q_len - 1])
-                        if id(st.request) not in first_t:
-                            first_t[id(st.request)] = time.perf_counter()
-                        counts[it.slot] += 1
-                        cur[it.slot] = tok
-                        if st.record(tok):
+                        # Speculative verification row: the chunk was [cur,
+                        # d_1..d_K]; target t_i = toks[slot, i] is the token the
+                        # sequential stream would sample after absorbing the
+                        # first i drafts. Accept the longest prefix d_1..d_a
+                        # with d_{i+1} == t_i, emit t_0..t_a (the bonus token t_a
+                        # rides for free), stopping early at EOS / new_limit as
+                        # a sequential stream would; then roll the uncommitted
+                        # chunk tail back out of the cache. The row's sample
+                        # count advances by exactly the tokens emitted — the
+                        # PRNG-stream guarantee that keeps sampled runs bitwise
+                        # identical to non-speculative serving.
+                        d = drafts.get(it.slot, [])[: it.n_draft]
+                        k = len(d)
+                        a = 0
+                        while a < k and d[a] == int(toks[it.slot, a]):
+                            a += 1
+                        emitted = 0
+                        finished = False
+                        for p in range(a + 1):
+                            tok = int(toks[it.slot, p])
+                            if id(st.request) not in first_t:
+                                first_t[id(st.request)] = time.perf_counter()
+                            emitted += 1
+                            cur[it.slot] = tok
+                            if st.record(tok):
+                                finished = True
+                                break
+                        counts[it.slot] += emitted
+                        n_roll = it.q_len - emitted
+                        if n_roll and not finished:
+                            pool.rollback(it.slot, n_roll)
+                        accepted = emitted - 1
+                        tally["draft"] += k
+                        tally["accept"] += accepted
+                        tally["roll"] += k - accepted
+                        self._m_draft_tok.inc(k)
+                        self._m_accept_tok.inc(accepted)
+                        self._m_rollback_tok.inc(k - accepted)
+                        if finished:
                             finish(it.slot)
-                        continue
-                    # Speculative verification row: the chunk was [cur,
-                    # d_1..d_K]; target t_i = toks[slot, i] is the token the
-                    # sequential stream would sample after absorbing the
-                    # first i drafts. Accept the longest prefix d_1..d_a
-                    # with d_{i+1} == t_i, emit t_0..t_a (the bonus token t_a
-                    # rides for free), stopping early at EOS / new_limit as
-                    # a sequential stream would; then roll the uncommitted
-                    # chunk tail back out of the cache. The row's sample
-                    # count advances by exactly the tokens emitted — the
-                    # PRNG-stream guarantee that keeps sampled runs bitwise
-                    # identical to non-speculative serving.
-                    d = drafts.get(it.slot, [])[: it.n_draft]
-                    k = len(d)
-                    a = 0
-                    while a < k and d[a] == int(toks[it.slot, a]):
-                        a += 1
-                    emitted = 0
-                    finished = False
-                    for p in range(a + 1):
-                        tok = int(toks[it.slot, p])
-                        if id(st.request) not in first_t:
-                            first_t[id(st.request)] = time.perf_counter()
-                        emitted += 1
-                        cur[it.slot] = tok
-                        if st.record(tok):
-                            finished = True
-                            break
-                    counts[it.slot] += emitted
-                    n_roll = it.q_len - emitted
-                    if n_roll and not finished:
-                        pool.rollback(it.slot, n_roll)
-                    accepted = emitted - 1
-                    tally["draft"] += k
-                    tally["accept"] += accepted
-                    tally["roll"] += k - accepted
-                    self._m_draft_tok.inc(k)
-                    self._m_accept_tok.inc(accepted)
-                    self._m_rollback_tok.inc(k - accepted)
-                    if finished:
-                        finish(it.slot)
-                if self.faults is not None and self.faults.fired_this_step:
-                    # Every injected fault is followed by a full pool
-                    # consistency audit at the very step that absorbed it.
-                    pool.check_invariants()
-                pool.emit_gauges()
-                # Widest decode/verify chunk of this step (K+1 under
-                # speculative decoding, 1 otherwise): the LLC models must
-                # see the query width each KV sweep is amortized over.
-                step_q = max(
-                    (it.q_len for it in plan if not it.is_prefill), default=1
-                )
-                if self.order_ctl is not None and self.order_ctl.enabled:
-                    # Adaptation drives its own sampling cadence (the
-                    # decision needs a fresh reading, not a stale gauge).
-                    if self.order_ctl.maybe_adapt(
-                        n_steps, pool, self.llc, step_q=step_q
-                    ):
-                        tr.instant(
-                            "serve.order_switch",
-                            order=self.order_ctl.order.value,
-                            step=n_steps,
-                        )
-                elif self.llc is not None:
-                    self.llc.maybe_sample(n_steps, pool, step_q=step_q)
+                    if self.faults is not None and self.faults.fired_this_step:
+                        # Every injected fault is followed by a full pool
+                        # consistency audit at the very step that absorbed it.
+                        pool.check_invariants()
+                    pool.emit_gauges()
+                    # Widest decode/verify chunk of this step (K+1 under
+                    # speculative decoding, 1 otherwise): the LLC models must
+                    # see the query width each KV sweep is amortized over.
+                    step_q = max(
+                        (it.q_len for it in plan if not it.is_prefill), default=1
+                    )
+                    if self.order_ctl is not None and self.order_ctl.enabled:
+                        # Adaptation drives its own sampling cadence (the
+                        # decision needs a fresh reading, not a stale gauge).
+                        if self.order_ctl.maybe_adapt(
+                            n_steps, pool, self.llc, step_q=step_q
+                        ):
+                            tr.instant(
+                                "serve.order_switch",
+                                order=self.order_ctl.order.value,
+                                step=n_steps,
+                            )
+                    elif self.llc is not None:
+                        self.llc.maybe_sample(n_steps, pool, step_q=step_q)
             self._m_step_time.observe(time.perf_counter() - t_iter)
             if self._log_every and n_steps and n_steps % self._log_every == 0:
                 self._log_stats_line(n_steps, pool, sched)

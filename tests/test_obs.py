@@ -168,6 +168,37 @@ def test_chrome_trace_schema(tmp_path):
     assert by_ph["i"]["args"] == {"width": 4}
 
 
+def test_record_appends_a_span_with_given_times():
+    tr = Tracer()
+    tr.record("serve.queued", 1_000, 250, rid=3)
+    (ev,) = tr.events()
+    assert (ev.name, ev.ts_ns, ev.dur_ns, ev.end_ns) == ("serve.queued", 1_000, 250, 1_250)
+    assert ev.args == {"rid": 3}
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    """Each ``span`` is also a profiler annotation under its own name, on
+    the host thread's line; ``record`` writes none."""
+    from jax.profiler import ProfileData
+
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("serve.step", step=0):
+            with tr.span("serve.dispatch"):
+                jax.block_until_ready(jax.numpy.ones(4) + 1)
+        tr.record("serve.queued", 0, 10, rid=0)
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    names = {
+        e.name
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+    assert {"serve.step", "serve.dispatch"} <= names
+    assert "serve.queued" not in names
+
+
 # ---- export sinks ------------------------------------------------------------
 
 
@@ -410,3 +441,64 @@ def test_static_path_records_latency_metrics(deepseek_lm):
     assert v("serve.step.tokens", kind="prefill") > 0
     names = {e.name for e in eng.tracer.events()}
     assert "serve.prefill" in names
+
+
+PHASES = ("serve.admit", "serve.plan_step", "serve.dispatch", "serve.wait_tokens", "serve.commit")
+
+
+def _within(outer, events):
+    return [e for e in events if outer.ts_ns <= e.ts_ns and e.end_ns <= outer.end_ns]
+
+
+def test_continuous_step_phases_queue_waits_and_kv_tokens(deepseek_lm):
+    lm, params = deepseek_lm
+    chunk, slots = 16, 3
+    # A budget of a whole chunk per slot: every prefill row takes min(chunk,
+    # what is left of its prompt), so the KV count below follows from the
+    # lengths alone.
+    eng = ServeEngine(
+        lm, params, batch_size=slots, max_len=96, scheduler="continuous",
+        page_size=16, prefill_chunk=chunk, token_budget=slots * chunk,
+    )
+    reqs = _requests(lm.cfg.vocab, [(5, 4), (19, 6), (33, 3), (9, 1), (12, 5)])
+    reqs[4].arrival = 5
+    results = eng.generate(reqs)
+    assert all(r.status == "ok" for r in results)
+    evs = eng.tracer.events()
+
+    # Every step that dispatched runs the five phases once each, in order,
+    # with the launch and the token wait inside the device step.
+    steps = [e for e in evs if e.name == "serve.step"]
+    dispatched = 0
+    for s in steps:
+        inner = sorted(_within(s, evs), key=lambda e: e.ts_ns)
+        dev = [e for e in inner if e.name == "serve.device_step"]
+        if not dev:
+            continue
+        dispatched += 1
+        assert [e.name for e in inner if e.name in PHASES] == list(PHASES)
+        assert sorted(e.name for e in _within(dev[0], inner) if e is not dev[0]) == [
+            "serve.dispatch", "serve.wait_tokens"]
+    assert dispatched == eng.last_stats.mixed_steps > 0
+
+    # One queue wait per request, from the stream's start (arrival step 0)
+    # or the boundary that reached its arrival step, to its admission.
+    queued = {e.args["rid"]: e for e in evs if e.name == "serve.queued"}
+    assert sorted(queued) == [r.rid for r in reqs]
+    t0_ns = queued[0].ts_ns
+    assert all(queued[r.rid].ts_ns == t0_ns for r in reqs[:4])
+    for r in results:
+        assert queued[r.rid].end_ns <= t0_ns + r.ttft_s * 1e9
+    reached = min((s for s in steps if s.args["step"] >= reqs[4].arrival),
+                  key=lambda s: s.ts_ns)
+    (admit,) = [e for e in _within(reached, evs) if e.name == "serve.admit"]
+    assert admit.ts_ns <= queued[4].ts_ns <= admit.end_ns
+
+    # Keys attended: each prefill chunk attends the prompt up to its end,
+    # each of the n - 1 decode steps the prompt and the tokens so far.
+    want = 0
+    for r, res in zip(reqs, results):
+        p, n = len(r.tokens), res.steps
+        want += sum(min(end, p) for end in range(chunk, p + chunk, chunk))
+        want += sum(p + j for j in range(1, n))
+    assert eng.obs.value("serve.step.kv_tokens") == want

@@ -90,3 +90,21 @@ def test_model_level_parity():
     lx, _ = jax.jit(lm_x.loss)(params, batch)
     lp, _ = jax.jit(lm_p.loss)(params, batch)
     assert abs(float(lx) - float(lp)) < 1e-4
+
+
+def test_kernel_is_named():
+    """The kernel carries a stable name for profiler traces. (The TPU
+    lowering has no ``cumsum`` yet, so unlike the attention kernels it
+    cannot be compiled for a described chip.)"""
+    x, dt, a, b, c = _inputs(0, 1, 64, 2, 8, 16)
+    jaxpr = jax.make_jaxpr(lambda *z: ssd_fwd(*z, chunk=32, interpret=True))(x, dt, a, b, c)
+
+    def names(j):
+        for e in j.eqns:
+            if e.primitive.name == "pallas_call":
+                yield str(e.params["name"])
+            for v in e.params.values():
+                if hasattr(v, "jaxpr"):
+                    yield from names(v.jaxpr)
+
+    assert list(names(jaxpr.jaxpr)) == ["ssd_fwd"]
