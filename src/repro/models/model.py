@@ -101,7 +101,7 @@ def _positions(b, s):
 def _ffn_fn_for(cfg: ModelConfig, *, serve: bool = False):
     if cfg.family == "moe" or (cfg.moe is not None):
         dropless = serve and cfg.moe_serve_dropless
-        return lambda p, c, h: MOE.moe_apply(p, c, h, dropless=dropless)
+        return lambda p, c, h, **kw: MOE.moe_apply(p, c, h, dropless=dropless, **kw)
     return None
 
 

@@ -1,14 +1,19 @@
-"""Top-k routed MoE FFN (GShard/Mixtral-style, capacity-based, static shapes).
+"""Top-k routed MoE FFN (GShard/Mixtral-style), in two forms.
 
-Dispatch uses a scatter into an (E, C, d) expert buffer and a gather back —
-fully static shapes so it lowers cleanly under pjit; with experts sharded on
-the 'model' axis GSPMD materializes the dispatch/combine as all-to-all-class
+Training (``moe_apply``, ``dropless=False``) uses the capacity path: a
+scatter into an (E, C, d) expert buffer and a gather back, fully static
+shapes so it lowers cleanly under pjit; with experts sharded on the 'model'
+axis GSPMD materializes the dispatch/combine as all-to-all-class
 collectives (the dominant collective term for the MoE archs, see
-EXPERIMENTS.md §Roofline).
+EXPERIMENTS.md §Roofline). Aux losses: load-balance (Switch-style over full
+softmax probs × dispatch fractions) + router z-loss; returned as a scalar
+the caller folds into the training loss.
 
-Aux losses: load-balance (Switch-style over full softmax probs × dispatch
-fractions) + router z-loss; returned as a scalar the caller folds into the
-training loss.
+Serving (``dropless=True``, ``_moe_dropless``) drops no token: the routed
+copies are sorted by expert and run through ``lax.ragged_dot`` grouped
+GEMMs. On the serve layer scan the expert weights stay whole ([L, E, ...])
+and each layer's experts are read in place from them (see
+``_moe_dropless``); the aux loss is zero.
 """
 
 from __future__ import annotations
@@ -22,7 +27,11 @@ from repro.configs.base import ModelConfig
 from repro.dist.context import constrain
 from repro.models import layers as L
 
-__all__ = ["moe_init", "moe_apply", "expert_capacity"]
+__all__ = ["moe_init", "moe_apply", "expert_capacity", "EXPERT_STACKS"]
+
+# The expert weight leaves of a MoE FFN: [E, ...] per layer, [L, E, ...]
+# stacked over a layer scan.
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
 def expert_capacity(n_tokens: int, cfg: ModelConfig) -> int:
@@ -47,17 +56,23 @@ def moe_init(key, cfg: ModelConfig) -> dict:
 
 
 def moe_apply(
-    p: dict, cfg: ModelConfig, x: jax.Array, *, dropless: bool = False
+    p: dict,
+    cfg: ModelConfig,
+    x: jax.Array,
+    *,
+    dropless: bool = False,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """x (B, S, d) -> (out (B, S, d), aux_loss scalar).
 
     dropless=True uses the sort + ``lax.ragged_dot`` grouped-GEMM path (no
-    capacity, no token dropping) — the serving configuration. Training uses
-    the capacity path (GShard-style) whose static buffer shapes shard
-    predictably under pjit.
+    capacity, no token dropping) — the serving configuration; ``layer``
+    selects its in-place read of whole expert stacks (``_moe_dropless``).
+    Training uses the capacity path (GShard-style) whose static buffer shapes
+    shard predictably under pjit.
     """
     if dropless:
-        return _moe_dropless(p, cfg, x)
+        return _moe_dropless(p, cfg, x, layer=layer)
     m = cfg.moe
     dt = cfg.activation_dtype()
     b, s, d = x.shape
@@ -106,8 +121,20 @@ def moe_apply(
     return y.astype(x.dtype), aux
 
 
-def _moe_dropless(p: dict, cfg: ModelConfig, x: jax.Array):
-    """Dropless grouped-GEMM MoE (vLLM/MegaBlocks-style) via lax.ragged_dot."""
+def _moe_dropless(p: dict, cfg: ModelConfig, x: jax.Array, *, layer=None):
+    """Dropless grouped-GEMM MoE (vLLM/MegaBlocks-style) via lax.ragged_dot.
+
+    With ``layer`` None, ``p`` is one layer's FFN: ``w_gate``/``w_up``
+    [E, d, ff] and ``w_down`` [E, ff, d]. With ``layer`` a traced int32
+    layer index, those three are the whole stacks of every layer ([L, E, ...])
+    and ``p["router"]`` is still layer ``layer``'s own. Each grouped GEMM then
+    runs on the [L*E, ...] view of its stack (a reshape that is a bitcast),
+    with the routed expert ids offset by ``layer * E``, so the group sizes are
+    zero outside that layer's E groups. The TPU ``ragged_dot`` kernel visits
+    only tiles of groups that have rows; taking a per-layer slice instead
+    would copy all E experts of the layer, since the custom call cannot fuse
+    a dynamic-slice operand.
+    """
     m = cfg.moe
     dt = cfg.activation_dtype()
     b, s, d = x.shape
@@ -124,12 +151,17 @@ def _moe_dropless(p: dict, cfg: ModelConfig, x: jax.Array):
     order = jnp.argsort(e_flat)  # stable in jnp
     inv = jnp.argsort(order)
     x_sorted = constrain(jnp.repeat(xf, k, axis=0)[order].astype(dt), "moe_tokens")
-    group_sizes = jnp.bincount(e_flat, length=e).astype(jnp.int32)
+    w_gate, w_up, w_down = (p[name] for name in EXPERT_STACKS)
+    if layer is None:  # one layer's experts: a stack of one
+        w_gate, w_up, w_down, layer = w_gate[None], w_up[None], w_down[None], 0
+    n = w_gate.shape[0] * e
+    group_sizes = jnp.bincount(e_flat + layer * e, length=n).astype(jnp.int32)
+    w_gate, w_up, w_down = (w.reshape(n, *w.shape[2:]) for w in (w_gate, w_up, w_down))
 
-    g = jax.lax.ragged_dot(x_sorted, p["w_gate"].astype(dt), group_sizes)
-    u = jax.lax.ragged_dot(x_sorted, p["w_up"].astype(dt), group_sizes)
+    g = jax.lax.ragged_dot(x_sorted, w_gate.astype(dt), group_sizes)
+    u = jax.lax.ragged_dot(x_sorted, w_up.astype(dt), group_sizes)
     h = jax.nn.silu(g) * u
-    y_sorted = jax.lax.ragged_dot(h, p["w_down"].astype(dt), group_sizes)
+    y_sorted = jax.lax.ragged_dot(h, w_down.astype(dt), group_sizes)
 
     y_flat = y_sorted[inv] * w_flat[:, None].astype(dt)
     y = y_flat.reshape(t, k, d).sum(axis=1).reshape(b, s, d)

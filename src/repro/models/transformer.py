@@ -20,6 +20,7 @@ from repro.dist import compression
 from repro.dist.context import constrain
 from repro.kernels import ops
 from repro.models import layers as L
+from repro.models.moe import EXPERT_STACKS
 
 __all__ = [
     "attn_init",
@@ -495,6 +496,39 @@ def stack_apply(
     return h, jnp.sum(auxes)
 
 
+def _split_expert_stacks(cfg: ModelConfig, params: dict):
+    """Split a serve stack's params into what the layer scan slices and the
+    dropless MoE expert stacks it must not: ``(scanned params, stacks,
+    layer indices)``.
+
+    ``lax.ragged_dot`` lowers to a TPU custom call, which cannot fuse a
+    dynamic-slice operand, so an expert stack scanned per layer is copied
+    whole (all E experts) every layer of every step. Where the layers' FFN
+    holds expert stacks ([L, E, ...]) and serving runs dropless, the stacks
+    leave the scanned params, are closed over whole, and the scan carries
+    the layer index instead (``moe._moe_dropless`` reads the layer's experts
+    in place). Dense stacks come back unchanged, with no stacks and no
+    indices.
+    """
+    ffn = params["ffn"]
+    if cfg.moe is None or not cfg.moe_serve_dropless or ffn["w_gate"].ndim != 4:
+        return params, None, None
+    stacks = {k: ffn[k] for k in EXPERT_STACKS}
+    rest = {k: v for k, v in ffn.items() if k not in stacks}
+    layers = jnp.arange(ffn["w_gate"].shape[0], dtype=jnp.int32)
+    return {**params, "ffn": rest}, stacks, layers
+
+
+def _serve_ffn(ffn_fn, cfg: ModelConfig, lp: dict, stacks, layer, x):
+    """The layer's FFN on a serve path: on ``lp["ffn"]`` alone, or with the
+    expert stacks whole and the layer index (``_split_expert_stacks``)."""
+    if stacks is None:
+        y = ffn_fn(lp["ffn"], cfg, x)
+    else:
+        y = ffn_fn({**lp["ffn"], **stacks}, cfg, x, layer=layer)
+    return y[0] if isinstance(y, tuple) else y
+
+
 def stack_prefill(
     params: dict,
     cfg: ModelConfig,
@@ -507,21 +541,23 @@ def stack_prefill(
     """Forward + build per-layer KV caches (stacked on a leading L axis)."""
     ffn_fn = ffn_apply_fn or (lambda p, c, h: ffn_apply(p, c, h))
     b = x.shape[0]
+    scanned, stacks, layers = _split_expert_stacks(cfg, params)
 
-    def body(h, lp):
+    def body(h, xs):
+        lp, layer = xs
         xn = L.rmsnorm(lp["ln_attn"], h, cfg.norm_eps)
         a, (k, v) = attn_apply(
             lp["attn"], cfg, xn, positions=positions, causal=True, return_kv=True
         )
         h = h + a
-        y = ffn_fn(lp["ffn"], cfg, L.rmsnorm(lp["ln_ffn"], h, cfg.norm_eps))
-        if isinstance(y, tuple):
-            y = y[0]
+        y = _serve_ffn(
+            ffn_fn, cfg, lp, stacks, layer, L.rmsnorm(lp["ln_ffn"], h, cfg.norm_eps)
+        )
         cache = fill_cache(cfg, init_cache(cfg, b, max_len), k, v)
         return constrain(h + y, "residual"), cache
 
     body = remat_wrap(body, cfg)
-    h, caches = layer_scan(cfg, body, x, params)
+    h, caches = layer_scan(cfg, body, x, (scanned, layers))
     return h, caches
 
 
@@ -535,16 +571,17 @@ def stack_decode(
 ):
     """One-token step through all layers, updating stacked caches."""
     ffn_fn = ffn_apply_fn or (lambda p, c, h: ffn_apply(p, c, h))
+    scanned, stacks, layers = _split_expert_stacks(cfg, params)
 
-    def body(h, scanned):
-        lp, cache = scanned
+    def body(h, xs):
+        lp, cache, layer = xs
         xn = L.rmsnorm(lp["ln_attn"], h, cfg.norm_eps)
         a, cache = attn_decode(lp["attn"], cfg, xn, cache)
         h = h + a
-        y = ffn_fn(lp["ffn"], cfg, L.rmsnorm(lp["ln_ffn"], h, cfg.norm_eps))
-        if isinstance(y, tuple):
-            y = y[0]
+        y = _serve_ffn(
+            ffn_fn, cfg, lp, stacks, layer, L.rmsnorm(lp["ln_ffn"], h, cfg.norm_eps)
+        )
         return h + y, cache
 
-    h, caches = layer_scan(cfg, body, x, (params, caches))
+    h, caches = layer_scan(cfg, body, x, (scanned, caches, layers))
     return h, caches

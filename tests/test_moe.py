@@ -1,10 +1,12 @@
 """MoE: routing invariants, capacity behavior, dropless == capacity@no-drop."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.models import moe
@@ -71,3 +73,108 @@ def test_grad_flows_through_router_and_experts():
     for name in ("router", "w_gate", "w_up", "w_down"):
         leaf = g[name]["w"] if isinstance(g[name], dict) else g[name]
         assert float(jnp.abs(leaf).sum()) > 0.0, name
+
+
+# --- in-place expert reads on the serve layer scan --------------------------
+
+_N_LAYERS = 4
+_SAME_8 = (0, 1, 17, 30, 31, 45, 62, 63)  # both ends of the layer's groups
+
+
+def _stacked_setup(routing):
+    """Reduced olmoe-1b-7b widths with its published routing (64 experts,
+    top-8) in bf16, the serving dtype, over a stack of ``_N_LAYERS`` layers.
+    ``routing`` biases each layer's router: ``some_empty`` shuts out every
+    odd expert, ``same_8`` sends every token to the experts of ``_SAME_8``."""
+    cfg = get_config("olmoe-1b-7b").reduced()
+    cfg = cfg.with_(
+        moe=dataclasses.replace(cfg.moe, num_experts=64, top_k=8),
+        dtype="bfloat16",
+        param_dtype="bfloat16",
+    )
+    keys = jax.random.split(jax.random.PRNGKey(3), _N_LAYERS)
+    stack = jax.vmap(lambda k: moe.moe_init(k, cfg))(keys)
+    e = cfg.moe.num_experts
+    if routing == "some_empty":
+        bias = jnp.where(jnp.arange(e) % 2 == 1, -1e4, 0.0)
+    else:
+        bias = jnp.full((e,), -1e4).at[jnp.asarray(_SAME_8)].set(
+            1e3 + jnp.arange(len(_SAME_8), dtype=jnp.float32)
+        )
+    stack["router"]["b"] = jnp.broadcast_to(bias, (_N_LAYERS, e))
+    return cfg, stack
+
+
+def _ulp_distance(a, b):
+    """Distance in bf16 units in the last place, element by element."""
+    def ordered(x):
+        bits = np.asarray(x).view(np.uint16).astype(np.int32)
+        return np.where(bits >= 0x8000, 0x8000 - bits, bits)
+
+    return np.abs(ordered(a) - ordered(b))
+
+
+@pytest.mark.parametrize("routing", ["some_empty", "same_8"])
+@pytest.mark.parametrize("n_tokens", [1, 2, 16, 37])
+@pytest.mark.parametrize("layer", [0, 1, _N_LAYERS - 1], ids=["first", "middle", "last"])
+def test_dropless_in_place_matches_layer_slice(layer, n_tokens, routing):
+    """Reading layer ``layer``'s experts in place from the whole [L, E, ...]
+    stacks equals the dropless path on the per-layer slice ``w[layer]``."""
+    cfg, stack = _stacked_setup(routing)
+    x = jax.random.normal(jax.random.PRNGKey(n_tokens), (1, n_tokens, cfg.d_model))
+    x = x.astype(jnp.bfloat16)
+    sliced = jax.tree.map(lambda a: a[layer], stack)
+    whole = {**sliced, **{k: stack[k] for k in moe.EXPERT_STACKS}}
+
+    logits = x.reshape(n_tokens, -1).astype(jnp.float32) @ sliced["router"]["w"]
+    _, sel = jax.lax.top_k(logits + sliced["router"]["b"], cfg.moe.top_k)
+    used = set(np.asarray(sel).ravel().tolist())
+    if routing == "same_8":
+        assert used == set(_SAME_8)
+    else:
+        assert used and all(i % 2 == 0 for i in used)
+
+    want, _ = _dropless_on_slice(sliced, cfg, x)
+    got, _ = _dropless_in_place(whole, cfg, x, jnp.int32(layer))
+    assert got.dtype == want.dtype == jnp.bfloat16
+    assert _ulp_distance(got, want).max() <= 1
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _dropless_on_slice(p, cfg, x):
+    return moe.moe_apply(p, cfg, x, dropless=True)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _dropless_in_place(p, cfg, x, layer):
+    return moe.moe_apply(p, cfg, x, dropless=True, layer=layer)
+
+
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "deepseek-7b"])
+def test_serve_scan_slices_no_expert_stack(arch):
+    """The serve decode scan takes dropless expert stacks whole (closed
+    over, not sliced per layer) and scans a dense model's params as before."""
+    from repro.models import build_model
+    from repro.models import transformer as T
+
+    cfg = get_config(arch).reduced()
+    lm = build_model(cfg)
+    params = jax.eval_shape(lm.init, jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: T.init_cache(cfg, 2, 16))
+    caches = jax.tree.map(
+        lambda c: jax.ShapeDtypeStruct((cfg.n_layers, *c.shape), c.dtype), caches
+    )
+    tokens = jax.ShapeDtypeStruct((2, 1), jnp.int32)
+    jaxpr = jax.make_jaxpr(lm.decode_step)(params, tokens, caches).jaxpr
+    (scan,) = [q for q in jaxpr.eqns if q.primitive.name == "scan"]
+    n_consts = scan.params["num_consts"] + scan.params["num_carry"]
+    scanned = {tuple(v.aval.shape) for v in scan.invars[n_consts:]}
+    ffn = params["layers"]["ffn"]
+    if cfg.moe is None:
+        assert {tuple(a.shape) for a in jax.tree.leaves(ffn)} <= scanned
+    else:
+        stacks = {tuple(ffn[k].shape) for k in moe.EXPERT_STACKS}
+        assert not stacks & scanned
+        closed = {tuple(v.aval.shape) for v in scan.invars[: scan.params["num_consts"]]}
+        assert stacks <= closed
+        assert (cfg.n_layers,) in scanned  # the layer index rides the scan

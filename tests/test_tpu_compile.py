@@ -6,7 +6,8 @@ would raise (tiling rules interpret mode never checks, a Mosaic kernel
 GSPMD cannot partition); ``memory_analysis`` shows whether a program fits
 the chip. Nothing runs, so these say nothing about results or times.
 Shapes are deepseek-7b's published widths as the chip smoke serves them:
-4 slots, ``max_len`` 512, 128-row pages (17 pool pages with the dummy).
+4 slots, ``max_len`` 512, 128-row pages (17 pool pages with the dummy);
+olmoe-1b-7b's as its benchmark cell serves them: 2 slots of 4096.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers import every file.
@@ -33,6 +34,7 @@ GiB = 2**30
 HBM_BYTES = 15.748 * GiB  # the allocator limit a v5e chip reports (16 GiB HBM)
 SLOTS, MAX_LEN, PAGE = 4, 512, 128
 N_PAGES = SLOTS * MAX_LEN // PAGE + 1
+MOE_SLOTS, MOE_MAX_LEN = 2, 4096
 
 
 @pytest.fixture(scope="module")
@@ -61,6 +63,11 @@ def one_chip(topo):
 def cfg():
     # attn_impl would resolve to "xla" on this CPU host; the chip runs pallas.
     return get_config("deepseek-7b").with_(attn_impl="pallas")
+
+
+@pytest.fixture(scope="module")
+def olmoe_cfg():
+    return get_config("olmoe-1b-7b").with_(attn_impl="pallas")
 
 
 def _spec(one_chip, shape, dtype):
@@ -181,32 +188,58 @@ def test_jitted_init_fits_one_chip(one_chip, cfg):
     assert _in_hbm(compiled) < HBM_BYTES
 
 
-def test_serve_mixed_step_fits_one_chip(one_chip, cfg):
-    """The engine's ragged mixed step at chunk width: weights, the donated
-    page pool and the step's temporaries together within one chip."""
+def _compile_mixed_step(one_chip, cfg, slots, max_len, width, chunk=None):
+    """The engine's ragged mixed step for ``slots`` x ``max_len`` rows of
+    ``PAGE``-row pages, at ``width`` tokens a row, on one described chip."""
     lm = build_model(cfg)
     params = jax.tree.map(
         lambda x: _spec(one_chip, x.shape, x.dtype),
         jax.eval_shape(lm.init, jax.random.PRNGKey(0)),
     )
     eng = ServeEngine(
-        lm, None, batch_size=SLOTS, max_len=MAX_LEN, scheduler="continuous",
-        page_size=PAGE,
+        lm, None, batch_size=slots, max_len=max_len, scheduler="continuous",
+        page_size=PAGE, prefill_chunk=chunk,
     )
     s = lambda shape, dt=jnp.int32: _spec(one_chip, shape, dt)
-    pages = s((cfg.n_layers, N_PAGES, cfg.n_kv_heads, PAGE, cfg.hd), jnp.bfloat16)
-    compiled = eng._mixed_step_fn().lower(
+    blocks = max_len // PAGE
+    pages = s((cfg.n_layers, slots * blocks + 1, cfg.n_kv_heads, PAGE, cfg.hd),
+              jnp.bfloat16)
+    return eng._mixed_step_fn().lower(
         params,
-        s((SLOTS, MAX_LEN)),
+        s((slots, width)),
         {"k_pages": pages, "v_pages": pages},
-        s((SLOTS, MAX_LEN // PAGE)),
-        s((SLOTS,)),
-        s((SLOTS,)),
+        s((slots, blocks)),
+        s((slots,)),
+        s((slots,)),
         s(()),
-        s((SLOTS,), jnp.float32),
-        s((SLOTS,)),
-        s((SLOTS,)),
+        s((slots,), jnp.float32),
+        s((slots,)),
+        s((slots,)),
     ).compile()
+
+
+def test_serve_mixed_step_fits_one_chip(one_chip, cfg):
+    """The engine's ragged mixed step at chunk width: weights, the donated
+    page pool and the step's temporaries together within one chip."""
+    compiled = _compile_mixed_step(one_chip, cfg, SLOTS, MAX_LEN, MAX_LEN)
     assert "paged_flash_decode_fwd" in _kernels(compiled)  # the name the benchmark reads
     assert compiled.memory_analysis().alias_size_in_bytes > 0.9 * GiB
     assert _in_hbm(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("width", [1, 512], ids=["narrow", "wide"])
+def test_moe_mixed_step_reads_experts_in_place(one_chip, olmoe_cfg, width):
+    """olmoe-1b-7b's dropless mixed step: the grouped GEMMs read each
+    layer's experts from the stacked weights, so no value shaped like one
+    layer's expert stack (a copy of all 64 experts) is made."""
+    compiled = _compile_mixed_step(
+        one_chip, olmoe_cfg, MOE_SLOTS, MOE_MAX_LEN, width, chunk=512
+    )
+    text = compiled.as_text()
+    m = olmoe_cfg.moe
+    d, ff = olmoe_cfg.d_model, m.d_ff_expert
+    for shape in ((m.num_experts, d, ff), (m.num_experts, ff, d)):
+        assert f"bf16[{','.join(map(str, shape))}]" not in text
+    assert "ragged-dot" in text
+    assert "paged_flash_decode_fwd" in _kernels(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * GiB
