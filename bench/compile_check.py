@@ -5,10 +5,12 @@ described TPU v5e, with no chip: a rehearsal before chip time.
 
 For each cell: the jitted weight initialisation, the engine's mixed step at
 both widths (prefill chunk and 1) with the cell's slots, pages and donated
-pool, and the reference's per-layer programs in float32 and in the fp8
-control. Prints one JSON line per program with the bytes the compiler
-places in HBM (arguments + outputs + temporaries - aliased). Nothing runs;
-the figures are the compiler's, not a chip's.
+pool (shaped by the program's own pool), and the programs the
+configuration's reference lists in ``compile_probes`` (a reference without
+them is skipped, with one line saying so). Prints one JSON line per
+program with the bytes the compiler places in HBM (arguments + outputs +
+temporaries - aliased). Nothing runs; the figures are the compiler's, not
+a chip's.
 """
 
 from __future__ import annotations
@@ -37,9 +39,10 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from bench import harness, serve, traffic
-    from bench.reference import decoder
     from repro.models import build_model
     from repro.serve import ServeEngine
+    from repro.serve.kv_pool import PagedKVPool
+    from repro.serve.tiering import TieredPagePool
 
     # A compile for a described chip cannot be read back from the cache.
     jax.config.update("jax_enable_compilation_cache", False)
@@ -75,29 +78,36 @@ def main(argv=None) -> int:
         eng = ServeEngine(lm, None, batch_size=mix["slots"], max_len=mix["max_len"],
                           scheduler="continuous", page_size=mix["page"],
                           prefill_chunk=mix["chunk"])
-        slots, page = mix["slots"], mix["page"]
-        blocks = -(-mix["max_len"] // page)
-        pages = spec_of((cfg.n_layers, slots * blocks + 1, cfg.n_kv_heads, page, cfg.hd),
-                        jnp.bfloat16)
+        slots = mix["slots"]
+        geometry = {}
+
+        def pool_pages():
+            # The pool generate() builds, from the engine's own settings.
+            kw = dict(prefix_sharing=eng.prefix_sharing, registry=eng.obs,
+                      admission=eng.admission, n_pages=eng.pool_pages, faults=eng.faults)
+            c = eng.lm.cfg
+            if eng.host_pages:
+                pool = TieredPagePool(c, c.n_layers, slots, eng._cap, host_pages=eng.host_pages,
+                                      **kw)
+            else:
+                pool = PagedKVPool(c, c.n_layers, slots, eng._cap, **kw)
+            geometry["block_tables"] = pool.block_tables.shape
+            return pool.pages
+
+        pages = jax.tree.map(lambda x: spec_of(x.shape, x.dtype), jax.eval_shape(pool_pages))
         for width in (mix["chunk"], 1):
             report(cell["name"], f"mixed step, width {width}", eng._mixed_step_fn(), params,
-                   spec_of((slots, width)), {"k_pages": pages, "v_pages": pages},
-                   spec_of((slots, blocks)), spec_of((slots,)), spec_of((slots,)), spec_of(()),
+                   spec_of((slots, width)), pages, spec_of(geometry["block_tables"]),
+                   spec_of((slots,)), spec_of((slots,)), spec_of(()),
                    spec_of((slots,), jnp.float32), spec_of((slots,)), spec_of((slots,)))
-        dm = decoder.Dims.of(config["model"])
-        key = spec_of((2,), jnp.uint32)
-        report(cell["name"], "reference layer weights",
-               lambda k: decoder._layer_weights(k, dm), key)
-        w = jax.tree.map(lambda x: spec_of(x.shape, x.dtype),
-                         jax.eval_shape(lambda k: decoder._layer_weights(k, dm),
-                                        jax.random.PRNGKey(0)))
-        x = spec_of((mix["max_len"], dm.d), jnp.float32)
-        for mode in ("f32", "fp8"):
-            report(cell["name"], f"reference layer, {mode}",
-                   lambda x, w, mode=mode: decoder._layer(x, w, dm, mode), x, w)
-        report(cell["name"], "reference logits",
-               lambda x, h: decoder._logits(x, h, dm, "f32"), x,
-               spec_of((dm.d, dm.vocab), jnp.float32))
+        ref = harness.load_reference(config["reference"])
+        if not hasattr(ref, "compile_probes"):
+            print(f"{cell['name']}: reference {config['reference']!r} lists no compile_probes; "
+                  "not sized", flush=True)
+            continue
+        for program, fn, *shapes in ref.compile_probes(config["model"], mix["max_len"]):
+            report(cell["name"], program, fn,
+                   *jax.tree.map(lambda x: spec_of(x.shape, x.dtype), shapes))
     return 0
 
 

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import work
+
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -256,3 +258,61 @@ def logit_gaps(m: dict, seed: int, tokens: np.ndarray, targets: np.ndarray,
         out[prefix + "logit_gap"] = widest
         out[prefix + "mean_logit_gap"] = total / max(n, 1)
     return out
+
+
+def logits(m: dict, seed: int, tokens: np.ndarray) -> np.ndarray:
+    """float32 logits (B, S, vocab) of tokens (B, S), each row a sequence
+    from position 0."""
+    dm, head, xs = forward(m, seed, tokens)
+    return np.stack([np.asarray(_logits(x, head, dm, "f32")) for x in xs["f32"]])
+
+
+def weight_pairs(m: dict, seed: int, params):
+    """(name, reference matrix, program matrix) for every matrix drawn from
+    the seed: the embedding table, the output head and each layer's
+    attention, FFN (each expert stack whole) and router weights. The
+    program's ``params`` hold the layers stacked on a leading axis."""
+    dm = Dims.of(m)
+    kh, layer_keys = model_keys(seed, dm.n_layers)
+    yield "embed", _embed_table(kh, dm).astype(jnp.float32), params["embed"]["table"]
+    yield "lm_head", _head(kh, dm), params["lm_head"]["w"]
+    attn, ffn = params["layers"]["attn"], params["layers"]["ffn"]
+    theirs = {k: attn[k]["w"] for k in ("wq", "wk", "wv", "wo")}
+    for ours, name in (("wg", "w_gate"), ("wu", "w_up"), ("wd", "w_down")):
+        theirs[ours] = ffn[name] if dm.experts else ffn[name]["w"]
+    if dm.experts:
+        theirs["router"] = ffn["router"]["w"]
+    for i in range(dm.n_layers):
+        w = _layer_weights(layer_keys[i], dm)
+        for k, stacked in theirs.items():
+            yield f"layer {i} {k}", w[k], stacked[i]
+
+
+def serve_flops(m: dict, tokens: float, pairs: int) -> float:
+    """Model FLOPs of a served forward: 2 per active matmul parameter per
+    token (the top-k experts of each MoE layer), plus causal attention,
+    QK^T and PV, once per query-key pair (``bench.work.serve_flops``)."""
+    return work.serve_flops(m, tokens, pairs)
+
+
+def cache_bytes(m: dict, kv_tokens: float) -> float:
+    """K and V bytes of ``kv_tokens`` keys, over all layers and KV heads."""
+    hd = m.get("head_dim") or m["d_model"] // m["n_heads"]
+    elem = jnp.dtype(m["kv_cache_dtype"]).itemsize
+    return kv_tokens * m["n_layers"] * 2 * m["n_kv_heads"] * hd * elem
+
+
+def compile_probes(m: dict, rows: int):
+    """(program name, function, *argument shapes) of what a comparison over
+    rows of ``rows`` positions compiles: one layer's weights, one layer in
+    float32 and in the fp8 control, and the output logits."""
+    dm = Dims.of(m)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    weights = lambda k: _layer_weights(k, dm)
+    x = jax.ShapeDtypeStruct((rows, dm.d), jnp.float32)
+    yield "reference layer weights", weights, key
+    for mode in ("f32", "fp8"):
+        yield (f"reference layer, {mode}", lambda x, w, mode=mode: _layer(x, w, dm, mode), x,
+               jax.eval_shape(weights, key))
+    yield ("reference logits", lambda x, h: _logits(x, h, dm, "f32"), x,
+           jax.ShapeDtypeStruct((dm.d, dm.vocab), jnp.float32))

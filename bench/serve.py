@@ -31,10 +31,10 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
-import importlib
 import os
 import sys
 import time
+import typing
 
 import numpy as np
 
@@ -45,12 +45,22 @@ NO_EOS = -1  # outputs run to the mix's lengths: no sampled id ends a request
 
 
 def model_config(model: dict):
-    """The program's ModelConfig for a configuration's ``model`` dict."""
-    from repro.configs.base import ModelConfig, MoEConfig
+    """The program's ModelConfig for a configuration's ``model`` dict: each
+    dict under a field typed as a dataclass (``moe``, ``ssm``, ...) becomes
+    that dataclass."""
+    from repro.configs.base import ModelConfig
 
-    m = dict(model)
-    moe = m.pop("moe", None)
-    return ModelConfig(**m, moe=MoEConfig(**moe) if moe else None)
+    return _from_dict(ModelConfig, model)
+
+
+def _from_dict(cls, d: dict):
+    hints = typing.get_type_hints(cls)
+    kw = {}
+    for k, v in d.items():
+        t = hints.get(k)  # an unknown field is left for the constructor to refuse
+        sub = [c for c in (t, *typing.get_args(t)) if dataclasses.is_dataclass(c)]
+        kw[k] = _from_dict(sub[0], v) if sub and isinstance(v, dict) else v
+    return cls(**kw)
 
 
 def model_dict(cfg) -> dict:
@@ -189,7 +199,7 @@ def reference_gaps(config: dict, model: dict, seed: int, sample: list, bucket: i
     """Logit gaps against the float32 reference over ``sample`` (see the
     reference's ``logit_gaps``). Each request is one row, right-padded to a
     multiple of ``bucket`` positions, so that few row lengths compile."""
-    ref = importlib.import_module(f"bench.reference.{config['reference']}")
+    ref = harness.load_reference(config["reference"])
     tokens, targets = [], []
     for s in sample:
         seq = np.concatenate([s.prompt, s.tokens[:-1]])
@@ -290,7 +300,7 @@ def run(*, cell: dict, spec: dict, seed: int, seconds: float, traced: bool, t_st
         r = harness.Readings(model=model, mix=mix, chips=cell["chips"], peak=peak,
                              window_s=w.window_s, spans=spans, counter=counter,
                              requests=[(len(s.prompt), len(s.tokens)) for s in w.served],
-                             trace=tr)
+                             trace=tr, config=config)
         for m in harness.metrics_for(spec, cell["name"], "per_layer"):
             v = harness.load_reader(m["name"])(r)
             if v is not None:

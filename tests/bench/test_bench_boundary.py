@@ -14,14 +14,15 @@ from bench.harness import load_reader
 from repro.obs import SpanEvent
 
 SPEC = harness.load_spec()
-DS7B = harness.load_config("deepseek-7b")["model"]
+DS7B_CONFIG = harness.load_config("deepseek-7b")
+DS7B = DS7B_CONFIG["model"]
 
 
 def readings(*, trace=None, spans=(), kv_tokens=0.0, peak=None):
     return harness.Readings(
         model=DS7B, mix={}, chips=1, peak=peak, window_s=1.0, spans=list(spans),
         counter=lambda name, **lb: kv_tokens if name == "serve.step.kv_tokens" else 0.0,
-        requests=[], trace=trace)
+        requests=[], trace=trace, config=DS7B_CONFIG)
 
 
 # Two steps in a 1000 ns window; device ops (busy 430 ns, idle 570 ns):

@@ -1,8 +1,10 @@
 """Each serve cell's path at the program's reduced size on the CPU, through
 the harness's own run (the look for a chip skipped): a sound run is
 correct, and the same run with the timed path broken underneath is not.
-The plain reference makes the program's weights from the seed and agrees
-with its forward pass; the control (fp8) fails the cell's limit."""
+Each cell's plain reference, loaded by name and used only through its
+contract (``bench/reference/__init__.py``), makes the program's weights from
+the seed and agrees with its forward pass; the control (fp8) fails the
+cell's limit."""
 
 import jax
 import jax.numpy as jnp
@@ -10,15 +12,27 @@ import numpy as np
 import pytest
 
 from bench import harness, serve, traffic
-from bench.reference import decoder
 
 SPEC = harness.load_spec()
 CELLS = [w["name"] for w in SPEC["workloads"] if w["chips"] == 1]
 SEED = 2**31 + 11
+# Equal to the last f32 bit or one ulp off: the program draws the layers
+# under vmap, and XLA may fuse the scaling of the normal draw differently
+# there. The served weights are bf16, which absorbs that. A reference
+# states another tolerance as WEIGHT_RTOL, with its reason.
+WEIGHT_RTOL = 3e-7
+# The program's forward at HIGHEST precision against the reference's
+# float32 one. A reference states another tolerance as LOGITS_TOL, with its
+# reason.
+LOGITS_TOL = 2e-4
 
 
 def reduced(cell):
     return serve.model_config(harness.load_config(cell["config"])["model"]).reduced()
+
+
+def reference(cell):
+    return harness.load_reference(harness.load_config(cell["config"])["reference"])
 
 
 def run_cell(name, *, traced=False):
@@ -105,32 +119,23 @@ def test_an_altered_token_is_not_correct(monkeypatch):
 def test_reference_makes_the_programs_weights(name):
     from repro.models import build_model
 
-    cfg = reduced(harness.find_cell(SPEC, name))
+    cell = harness.find_cell(SPEC, name)
+    ref, cfg = reference(cell), reduced(cell)
     params = build_model(cfg).init(jax.random.PRNGKey(SEED))
-    dm = decoder.Dims.of(serve.model_dict(cfg))
-    kh, keys = decoder.model_keys(SEED, dm.n_layers)
-    head = decoder._head(kh, dm)
-    # Equal to the last f32 bit or one ulp off: the program draws the
-    # layers under vmap, and XLA may fuse the scaling of the normal draw
-    # differently there. The served weights are bf16, which absorbs that.
-    same = lambda a, b: np.testing.assert_allclose(a, b, rtol=3e-7, atol=0)
-    same(head, params["lm_head"]["w"])
-    for i in range(dm.n_layers):
-        w = decoder._layer_weights(keys[i], dm)
-        lp = jax.tree.map(lambda a: a[i], params["layers"])
-        for ours, theirs in [("wq", lp["attn"]["wq"]["w"]), ("wo", lp["attn"]["wo"]["w"]),
-                             ("wg", lp["ffn"]["w_gate"] if cfg.moe else lp["ffn"]["w_gate"]["w"]),
-                             ("wd", lp["ffn"]["w_down"] if cfg.moe else lp["ffn"]["w_down"]["w"])]:
-            same(w[ours], theirs)
-        if cfg.moe:
-            same(w["router"], lp["ffn"]["router"]["w"])
+    rtol = getattr(ref, "WEIGHT_RTOL", WEIGHT_RTOL)
+    names = []
+    for what, ours, theirs in ref.weight_pairs(serve.model_dict(cfg), SEED, params):
+        np.testing.assert_allclose(ours, theirs, rtol=rtol, atol=0, err_msg=what)
+        names.append(what)
+    assert names and len(set(names)) == len(names)
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_reference_agrees_with_the_programs_forward(name):
     from repro.models import build_model
 
-    cfg = reduced(harness.find_cell(SPEC, name)).with_(attn_impl="reference")
+    cell = harness.find_cell(SPEC, name)
+    ref, cfg = reference(cell), reduced(cell).with_(attn_impl="reference")
     lm = build_model(cfg)
     params = lm.init(jax.random.PRNGKey(SEED))
     rng = np.random.default_rng(0)
@@ -138,9 +143,24 @@ def test_reference_agrees_with_the_programs_forward(name):
     tokens = rng.integers(traffic.FIRST_ID, cfg.vocab, size=(2, s)).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         want, _ = lm.prefill(params, {"tokens": jnp.asarray(tokens)}, s)
-    dm, head, xs = decoder.forward(serve.model_dict(cfg), SEED, tokens)
-    got = np.stack([decoder._logits(x, head, dm, "f32")[-1] for x in xs["f32"]])
-    np.testing.assert_allclose(got, want[:, 0], rtol=2e-4, atol=2e-4)
+    got = ref.logits(serve.model_dict(cfg), SEED, tokens)
+    assert got.shape == (2, s, cfg.vocab) and got.dtype == np.float32
+    tol = getattr(ref, "LOGITS_TOL", LOGITS_TOL)
+    np.testing.assert_allclose(got[:, -1], want[:, 0], rtol=tol, atol=tol)
+
+
+def _program_configs():
+    from repro.configs.registry import get_config
+
+    bench = [serve.model_config(harness.load_config(c["name"])["model"]) for c in SPEC["configs"]]
+    return bench + [get_config("mamba2-130m"), get_config("zamba2-2_7b")]
+
+
+@pytest.mark.parametrize("cfg", _program_configs(), ids=lambda c: c.name)
+def test_model_config_turns_every_sub_config_back_into_its_dataclass(cfg):
+    back = serve.model_config(serve.model_dict(cfg))
+    assert back == cfg
+    assert type(back.moe) is type(cfg.moe) and type(back.ssm) is type(cfg.ssm)
 
 
 @pytest.mark.parametrize("name", CELLS)

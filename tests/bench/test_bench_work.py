@@ -1,11 +1,13 @@
 """Work counts and peaks: parameter counts against the program's own
-initialisation shapes, causal FLOPs against a brute-force count."""
+initialisation shapes, causal FLOPs against a brute-force count, and the
+two shares that read a configuration's counts from its reference."""
 
 import jax
 import numpy as np
 import pytest
 
 from bench import harness, peaks, serve, work
+from bench import trace as T
 
 CONFIGS = ["deepseek-7b", "olmoe-1b-7b"]
 
@@ -62,3 +64,38 @@ def test_unknown_device_kind_raises():
     assert peaks.peak_for("TPU v5 lite")["bf16_flops"] == 197e12
     with pytest.raises(KeyError):
         peaks.peak_for("cpu")
+
+
+def _readings(model, config):
+    """Readings of a hand-made window: 51 s on one v5e, 4,000 decode and
+    2,500 prefill tokens, and one step of the paged kernel, 7.331 ms over
+    550 keys."""
+    counts = {("serve.step.tokens", "decode"): 4000.0, ("serve.step.tokens", "prefill"): 2500.0,
+              ("serve.step.kv_tokens", None): 550.0}
+    ops = [T.Op("paged_flash_decode_fwd.7", "bf16[128,8,128]", 1000, 7_332_000)]
+    return harness.Readings(model, {}, 1, peaks.peak_for("TPU v5 lite"), 51.0, [],
+                            lambda name, **lb: counts[(name, lb.get("kind"))],
+                            [(117, 181), (338, 20), (4064, 2), (9, 0)],
+                            T.Trace({"/device:TPU:0": ops}, [], (0, 51_000_000_000)), config)
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_shares_read_the_counts_they_read_before_references_gave_them(name):
+    config = harness.load_config(name)
+    m = config["model"]
+    r = _readings(m, config)
+    hd = m.get("head_dim") or m["d_model"] // m["n_heads"]
+    # mfu.serve: 2 per active matmul parameter per token, causal QK^T and PV once.
+    pairs = sum(work.causal_pairs(0, p + n - 1) for p, n in r.requests if n)
+    flops = 2.0 * work.matmul_params(m)[1] * 6500.0 + 4 * m["n_layers"] * m["n_heads"] * hd * pairs
+    assert harness.load_reader("mfu.serve")(r) == 100.0 * flops / (51.0 * 1 * 197e12)
+    # paged_attn_roofline: K and V of every key attended, over all layers, in bf16.
+    byts = 550.0 * m["n_layers"] * 2 * m["n_kv_heads"] * hd * 2
+    want = 100.0 * byts / 1 / (T.op_seconds(r.trace, "paged_flash_decode_fwd") * 819e9)
+    assert harness.load_reader("paged_attn_roofline")(r) == want
+
+
+def test_readings_without_a_configuration_have_no_reference():
+    r = _readings(harness.load_config("deepseek-7b")["model"], None)
+    with pytest.raises(ValueError, match="no configuration"):
+        harness.load_reader("mfu.serve")(r)
