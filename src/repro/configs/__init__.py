@@ -1,5 +1,6 @@
 from repro.configs.base import (
     SHAPES,
+    MLAConfig,
     ModelConfig,
     MoEConfig,
     ParallelConfig,
@@ -11,6 +12,7 @@ from repro.configs.registry import ARCH_IDS, all_configs, get_config
 
 __all__ = [
     "SHAPES",
+    "MLAConfig",
     "ModelConfig",
     "MoEConfig",
     "ParallelConfig",

@@ -12,6 +12,7 @@ from typing import Optional, Sequence
 import jax.numpy as jnp
 
 __all__ = [
+    "MLAConfig",
     "MoEConfig",
     "SSMConfig",
     "ModelConfig",
@@ -30,6 +31,56 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_z_coef: float = 1e-3
     aux_loss_coef: float = 1e-2
+    # Routed experts this chip holds of each layer, ids [0, experts_held):
+    # the router still scores all ``num_experts`` and picks ``top_k``, and
+    # assignments to experts held elsewhere contribute nothing here (one
+    # chip of an expert-parallel deployment). None holds every expert.
+    experts_held: Optional[int] = None
+    # Shared experts every token passes through, each ``d_ff_expert`` wide
+    # (run as one SwiGLU of n_shared_experts * d_ff_expert).
+    n_shared_experts: int = 0
+    # True: the top-k weights are a softmax over the k selected logits.
+    # False: they are the selected experts' probabilities under a softmax
+    # over all ``num_experts`` (DeepSeek-V2, OLMoE as published).
+    norm_topk_prob: bool = True
+
+    @property
+    def held(self) -> int:
+        return self.num_experts if self.experts_held is None else self.experts_held
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values are made
+    per head from one cached latent per token, ``kv_lora_rank`` wide, beside
+    one shared rotary key ``qk_rope_head_dim`` wide. RoPE is YaRN-scaled by
+    ``rope_factor`` over ``rope_original_max`` positions (factor 1: plain)."""
+
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    q_lora_rank: Optional[int] = None     # a low-rank query path is not built
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
+
+    def __post_init__(self):
+        if self.q_lora_rank is not None:
+            raise ValueError("latent attention takes queries from one plain projection "
+                             "(q_lora_rank None)")
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of the cached latent: the compressed KV and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,6 +112,9 @@ class ModelConfig:
     norm_eps: float = 1e-5
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    mla: Optional[MLAConfig] = None         # latent attention in every layer
+    first_dense_layers: int = 0             # leading layers with a dense
+                                            # d_ff FFN ahead of the MoE stack
     # encdec
     n_encoder_layers: int = 0
     # vlm / audio stubs: number of prefix embedding positions fed by the
@@ -143,9 +197,24 @@ class ModelConfig:
             remat="none",
         )
         if self.moe is not None:
+            top_k = min(self.moe.top_k, 2)
+            held = self.moe.experts_held
             kw["moe"] = dataclasses.replace(
-                self.moe, num_experts=4, top_k=min(self.moe.top_k, 2), d_ff_expert=32
+                self.moe, num_experts=4, top_k=top_k, d_ff_expert=32,
+                experts_held=None if held is None
+                else max(top_k, held * 4 // self.moe.num_experts),
             )
+        if self.mla is not None:
+            kw["mla"] = dataclasses.replace(
+                self.mla, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                v_head_dim=16,
+            )
+            # Two query heads on the one latent head: the CPU's paged
+            # attention scores every head of a 512-row chunk against every
+            # page, which long-document traffic makes the tests' main cost.
+            kw["n_heads"] = 2
+        if self.first_dense_layers:
+            kw["first_dense_layers"] = 1
         if self.ssm is not None:
             kw["ssm"] = dataclasses.replace(
                 self.ssm,

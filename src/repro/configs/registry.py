@@ -11,6 +11,7 @@ ARCH_IDS = [
     "mixtral-8x7b",
     "llama3-405b",
     "deepseek-7b",
+    "deepseek-v2-lite",
     "qwen2-72b",
     "codeqwen1_5-7b",
     "seamless-m4t-medium",

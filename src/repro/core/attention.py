@@ -422,8 +422,12 @@ def decode_attention(
     order: Order | str = Order.CYCLIC,
     snake_group: Optional[int] = None,
     order_group: Optional[jax.Array] = None,
+    v_dim: Optional[int] = None,
 ) -> jax.Array:
     """Single-position decode attention against a (possibly padded) KV cache.
+
+    ``v_cache`` None reads V as the first ``v_dim`` columns of K (a latent
+    cache); the result is then ``v_dim`` wide.
 
     Contiguous layout: q (B, 1, Hq, D); caches (B, S_max, Hkv, D);
     cache_len: valid prefix length (scalar or (B,)). Linear in S_max — used
@@ -455,9 +459,12 @@ def decode_attention(
             order=order,
             snake_group=snake_group,
             order_group=order_group,
+            v_dim=v_dim,
         )
     assert q_lens is None, "q_lens requires the paged layout (block_table)"
     assert order_group is None, "order_group requires the paged layout"
+    if v_cache is None:
+        v_cache = k_cache[..., :v_dim]
     b, one, hq, d = q.shape
     assert one == 1
     _, s_max, hkv, _ = k_cache.shape
@@ -473,7 +480,7 @@ def decode_attention(
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bkhd->bhgd", p, v_cache.astype(jnp.float32))
-    return o.reshape(b, 1, hq, d).astype(q.dtype)
+    return o.reshape(b, 1, hq, v_cache.shape[-1]).astype(q.dtype)
 
 
 def paged_decode_attention(
@@ -489,6 +496,7 @@ def paged_decode_attention(
     order: Order | str = Order.CYCLIC,
     snake_group: Optional[int] = None,
     order_group: Optional[jax.Array] = None,
+    v_dim: Optional[int] = None,
 ) -> jax.Array:
     """Blockwise ragged attention over a paged KV pool, schedule-ordered.
 
@@ -514,8 +522,13 @@ def paged_decode_attention(
 
     Fully-masked rows (q_len 0 / len 0 — e.g. a free slot in a
     continuous-batching pool) return exact zeros rather than NaN.
+
+    ``v_pool`` None is a latent pool: V is the first ``v_dim`` columns of
+    each K page (whose columns past q's width are zero padding), and the
+    result is ``v_dim`` wide.
     """
     b, c, hq, d = q.shape
+    dv = v_dim if v_pool is None else v_pool.shape[-1]
     n_pages, hkv, page, _ = k_pool.shape
     n_blocks = block_table.shape[1]
     g = hq // hkv
@@ -555,7 +568,8 @@ def paged_decode_attention(
         logical = jax.lax.dynamic_index_in_dim(visit, j, axis=1, keepdims=False)
         pid = jax.lax.dynamic_index_in_dim(phys, j, axis=1, keepdims=False)
         k_j = k_pool[pid].astype(jnp.float32)  # (B, Hkv, page, D)
-        v_j = v_pool[pid].astype(jnp.float32)
+        v_j = k_j[..., :dv] if v_pool is None else v_pool[pid].astype(jnp.float32)
+        k_j = k_j[..., :d]  # a latent pool's rows are zero-padded past q's width
         pos = logical[:, None] * page + offs   # (B, page) absolute positions
         # (B, C, page): kv visible to query row t iff within [0, len),
         # causally at-or-before the query's own position, and the query
@@ -577,9 +591,9 @@ def paged_decode_attention(
     init = (
         jnp.full((b, hkv, g, c), NEG_INF, jnp.float32),
         jnp.zeros((b, hkv, g, c), jnp.float32),
-        jnp.zeros((b, hkv, g, c, d), jnp.float32),
+        jnp.zeros((b, hkv, g, c, dv), jnp.float32),
     )
     (m, l, acc), _ = jax.lax.scan(body, init, jnp.arange(n_blocks))
     l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows (free slots)
-    o = acc / l[..., None]           # (B, Hkv, G, C, D)
-    return o.transpose(0, 3, 1, 2, 4).reshape(b, c, hq, d).astype(q.dtype)
+    o = acc / l[..., None]           # (B, Hkv, G, C, Dv)
+    return o.transpose(0, 3, 1, 2, 4).reshape(b, c, hq, dv).astype(q.dtype)

@@ -271,8 +271,12 @@ def attention_decode(
     q_lens: Optional[jax.Array] = None,
     snake_group: Optional[int] = None,
     order_group: Optional[jax.Array] = None,
+    v_dim: Optional[int] = None,
 ) -> jax.Array:
     """Decode / ragged-chunk attention vs a KV cache. Not differentiated.
+
+    ``v_cache`` None reads V as the first ``v_dim`` columns of K: a latent
+    cache (multi-head latent attention), whose one page tile is both.
 
     ``block_table`` switches both backends to the paged layout: caches are
     shared (n_pages, Hkv, page, D) pools and pages are visited in schedule
@@ -304,6 +308,7 @@ def attention_decode(
             block_table=block_table,
             q_lens=q_lens,
             order_group=order_group,
+            **({} if v_dim is None else {"v_dim": v_dim}),
         )
     if impl in ("xla", "reference"):
         return core_attn.decode_attention(
@@ -318,6 +323,7 @@ def attention_decode(
             order=order,
             snake_group=snake_group,
             order_group=order_group,
+            v_dim=v_dim,
         )
     raise ValueError(f"unknown decode impl: {impl!r}")
 

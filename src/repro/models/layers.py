@@ -18,6 +18,7 @@ __all__ = [
     "dense",
     "rmsnorm_init",
     "rmsnorm",
+    "swiglu",
     "layernorm_init",
     "layernorm",
     "embed_init",
@@ -58,6 +59,13 @@ def dense(p: dict, x: jax.Array, *, dtype=None) -> jax.Array:
     return y
 
 
+def swiglu(p: dict, x: jax.Array, *, dtype=None) -> jax.Array:
+    """SwiGLU FFN on dense params ``w_gate``, ``w_up``, ``w_down``."""
+    g = dense(p["w_gate"], x, dtype=dtype)
+    u = dense(p["w_up"], x, dtype=dtype)
+    return dense(p["w_down"], jax.nn.silu(g) * u, dtype=dtype)
+
+
 def rmsnorm_init(dim: int, dtype=jnp.float32) -> dict:
     return {"scale": jnp.ones((dim,), dtype)}
 
@@ -88,12 +96,20 @@ def embed_init(key: jax.Array, vocab: int, dim: int, dtype=jnp.float32) -> dict:
 
 
 def rope(
-    x: jax.Array, positions: jax.Array, *, theta: float = 10000.0
+    x: jax.Array,
+    positions: jax.Array,
+    *,
+    theta: float = 10000.0,
+    inv_freq: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Rotary position embedding. x: (..., S, H, D), positions: (..., S)."""
+    """Rotary position embedding. x: (..., S, H, D), positions: (..., S).
+    ``inv_freq`` (D // 2,) replaces the plain ``theta`` frequencies (YaRN)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = inv_freq
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, half)
     cos = jnp.cos(angles)[..., :, None, :]  # (..., S, 1, half)
     sin = jnp.sin(angles)[..., :, None, :]

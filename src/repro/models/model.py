@@ -111,16 +111,50 @@ def _ffn_init_for(cfg: ModelConfig):
     return None
 
 
+def _split_caches(cfg: ModelConfig, caches: dict) -> tuple[dict, dict]:
+    """The leading dense stack's cache pytree and the main stack's, out of
+    the model's: each stack's own leaves (the dense stack's named with
+    ``T.DENSE_PREFIX``) and its layers' slice of the per-row metadata."""
+    k = cfg.first_dense_layers
+    meta = {n: caches[n] for n in T.CACHE_META if n in caches}
+    p = T.DENSE_PREFIX
+    dense = {n[len(p):]: v for n, v in caches.items() if n.startswith(p)}
+    main = {n: v for n, v in caches.items() if n not in meta and not n.startswith(p)}
+    dense.update({n: v[:k] for n, v in meta.items()})
+    main.update({n: v[k:] for n, v in meta.items()})
+    return dense, main
+
+
+def _join_caches(dense: dict, main: dict) -> dict:
+    """Inverse of :func:`_split_caches`."""
+    out = {T.DENSE_PREFIX + n: v for n, v in dense.items() if n not in T.CACHE_META}
+    out.update({n: v for n, v in main.items() if n not in T.CACHE_META})
+    for n in T.CACHE_META:
+        if n in main:
+            out[n] = jnp.concatenate([dense[n], main[n]])
+    return out
+
+
 def _build_decoder_only(cfg: ModelConfig) -> LM:
     ffn_fn = _ffn_fn_for(cfg)
     ffn_fn_serve = _ffn_fn_for(cfg, serve=True)
     ffn_init = _ffn_init_for(cfg)
     is_vlm = cfg.family == "vlm"
+    # Leading dense layers (``first_dense_layers``) run as a stack of their
+    # own ahead of the main one: a layer scan takes layers of one kind.
+    n_dense = cfg.first_dense_layers
 
     def init(key):
         kh, ks, kp = L.split_keys(key, 3)
         p = _head_init(kh, cfg)
-        p["layers"] = T.stack_init(ks, cfg, cfg.n_layers, ffn_init_fn=ffn_init)
+        if n_dense:
+            keys = jnp.stack(L.split_keys(ks, cfg.n_layers))
+            p["dense_layers"] = jax.vmap(lambda k: T.layer_init(k, cfg))(keys[:n_dense])
+            p["layers"] = jax.vmap(
+                lambda k: T.layer_init(k, cfg, ffn_init_fn=ffn_init)
+            )(keys[n_dense:])
+        else:
+            p["layers"] = T.stack_init(ks, cfg, cfg.n_layers, ffn_init_fn=ffn_init)
         p["ln_f"] = L.rmsnorm_init(cfg.d_model, cfg.parameter_dtype())
         if is_vlm:
             p["vision_proj"] = L.dense_init(kp, cfg.d_model, cfg.d_model, dtype=cfg.parameter_dtype())
@@ -143,6 +177,8 @@ def _build_decoder_only(cfg: ModelConfig) -> LM:
     def loss(params, batch):
         x, tokens, mask = _embed_batch(params, batch)
         b, s, _ = x.shape
+        if n_dense:
+            x, _ = T.stack_apply(params["dense_layers"], cfg, x, _positions(b, s), causal=True)
         h, aux = T.stack_apply(
             params["layers"], cfg, x, _positions(b, s), causal=True, ffn_apply_fn=ffn_fn
         )
@@ -152,17 +188,28 @@ def _build_decoder_only(cfg: ModelConfig) -> LM:
     def prefill(params, batch, max_len):
         x, tokens, _ = _embed_batch(params, batch)
         b, s, _ = x.shape
+        if n_dense:
+            x, dense = T.stack_prefill(
+                params["dense_layers"], cfg, x, _positions(b, s), max_len
+            )
         h, caches = T.stack_prefill(
             params["layers"], cfg, x, _positions(b, s), max_len, ffn_apply_fn=ffn_fn_serve
         )
+        if n_dense:
+            caches = _join_caches(dense, caches)
         h = L.rmsnorm(params["ln_f"], h[:, -1:], cfg.norm_eps)
         return _logits(params, cfg, h), caches
 
     def decode_step(params, tokens, caches):
         x = _embed_tokens(params, cfg, tokens)  # (B, 1)
+        if n_dense:
+            dense, caches = _split_caches(cfg, caches)
+            x, dense = T.stack_decode(params["dense_layers"], cfg, x, dense)
         h, caches = T.stack_decode(
             params["layers"], cfg, x, caches, ffn_apply_fn=ffn_fn_serve
         )
+        if n_dense:
+            caches = _join_caches(dense, caches)
         h = L.rmsnorm(params["ln_f"], h, cfg.norm_eps)
         return _logits(params, cfg, h), caches
 

@@ -14,6 +14,12 @@ copies are sorted by expert and run through ``lax.ragged_dot`` grouped
 GEMMs. On the serve layer scan the expert weights stay whole ([L, E, ...])
 and each layer's experts are read in place from them (see
 ``_moe_dropless``); the aux loss is zero.
+
+Both forms take the DeepSeek-V2 variants of ``MoEConfig``: shared experts
+every token passes through, top-k weights left as probabilities over all
+experts (``norm_topk_prob`` False), and one chip's share of the routed
+experts (``experts_held``): the router scores every expert, and the
+assignments to experts held elsewhere contribute nothing here.
 """
 
 from __future__ import annotations
@@ -41,18 +47,49 @@ def expert_capacity(n_tokens: int, cfg: ModelConfig) -> int:
 
 
 def moe_init(key, cfg: ModelConfig) -> dict:
+    """Router over all ``num_experts``, the held experts' stacks
+    ([held, ...]) and, with shared experts, one dense SwiGLU ``shared`` of
+    ``n_shared_experts * d_ff_expert``."""
     m = cfg.moe
-    d, ff, e = cfg.d_model, m.d_ff_expert, m.num_experts
-    kr, kg, ku, kd = L.split_keys(key, 4)
+    d, ff, e, held = cfg.d_model, m.d_ff_expert, m.num_experts, m.held
+    kr, kg, ku, kd, *ks = L.split_keys(key, 5 if m.n_shared_experts else 4)
     pd = cfg.parameter_dtype()
     s_in = 1.0 / math.sqrt(d)
     s_out = 1.0 / math.sqrt(ff)
-    return {
+    p = {
         "router": L.dense_init(kr, d, e, dtype=jnp.float32),
-        "w_gate": (jax.random.normal(kg, (e, d, ff)) * s_in).astype(pd),
-        "w_up": (jax.random.normal(ku, (e, d, ff)) * s_in).astype(pd),
-        "w_down": (jax.random.normal(kd, (e, ff, d)) * s_out).astype(pd),
+        "w_gate": (jax.random.normal(kg, (held, d, ff)) * s_in).astype(pd),
+        "w_up": (jax.random.normal(ku, (held, d, ff)) * s_in).astype(pd),
+        "w_down": (jax.random.normal(kd, (held, ff, d)) * s_out).astype(pd),
     }
+    if m.n_shared_experts:
+        fs = m.n_shared_experts * ff
+        kg, ku, kd = L.split_keys(ks[0], 3)
+        p["shared"] = {
+            "w_gate": L.dense_init(kg, d, fs, dtype=pd),
+            "w_up": L.dense_init(ku, d, fs, dtype=pd),
+            "w_down": L.dense_init(kd, fs, d, dtype=pd),
+        }
+    return p
+
+
+def _route(p: dict, cfg: ModelConfig, xf: jax.Array):
+    """(router logits (T, E) f32, selected experts (T, k), their weights)."""
+    m = cfg.moe
+    logits = L.dense(p["router"], xf.astype(jnp.float32))
+    top_logits, sel = jax.lax.top_k(logits, m.top_k)
+    if m.norm_topk_prob:
+        weights = jax.nn.softmax(top_logits, axis=-1)
+    else:
+        weights = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1), sel, axis=-1)
+    return logits, sel, weights
+
+
+def _with_shared(p: dict, cfg: ModelConfig, x: jax.Array, y: jax.Array) -> jax.Array:
+    """The routed experts' output ``y`` plus the shared experts' on ``x``."""
+    if "shared" not in p:
+        return y
+    return y + L.swiglu(p["shared"], x, dtype=cfg.activation_dtype()).astype(y.dtype)
 
 
 def moe_apply(
@@ -77,15 +114,13 @@ def moe_apply(
     dt = cfg.activation_dtype()
     b, s, d = x.shape
     t = b * s
-    e, k = m.num_experts, m.top_k
+    e, k, held = m.num_experts, m.top_k, m.held
     cap = expert_capacity(t, cfg)
 
     xf = x.reshape(t, d)
-    logits = L.dense(p["router"], xf.astype(jnp.float32))  # (T, E) f32 router
+    logits, sel, weights = _route(p, cfg, xf)  # (T, E) f32 router; (T, k)
     probs = jax.nn.softmax(logits, axis=-1)
-
-    top_logits, sel = jax.lax.top_k(logits, k)  # (T, k)
-    weights = jax.nn.softmax(top_logits, axis=-1).astype(jnp.float32)
+    weights = weights.astype(jnp.float32)
 
     # --- flat assignment stream (token-major priority) ---------------------
     e_flat = sel.reshape(-1)  # (T*k,)
@@ -95,11 +130,15 @@ def moe_apply(
     pos = jnp.take_along_axis(pos_all, e_flat[:, None], axis=1)[:, 0]  # (T*k,)
     keep = (pos < cap).astype(jnp.float32)
     pos_c = jnp.minimum(pos, cap - 1)
+    e_buf = e_flat
+    if held < e:  # assignments to experts held elsewhere are not computed here
+        keep = keep * (e_flat < held)
+        e_buf = jnp.where(e_flat < held, e_flat, 0)
 
-    # --- dispatch: scatter tokens into (E, C, d) buffers --------------------
+    # --- dispatch: scatter tokens into (held, C, d) buffers -----------------
     x_rep = jnp.repeat(xf, k, axis=0).astype(dt)  # (T*k, d)
-    buf = jnp.zeros((e, cap, d), dt)
-    buf = buf.at[e_flat, pos_c].add(x_rep * keep[:, None].astype(dt))
+    buf = jnp.zeros((held, cap, d), dt)
+    buf = buf.at[e_buf, pos_c].add(x_rep * keep[:, None].astype(dt))
     buf = constrain(buf, "moe_buffer")
 
     # --- expert SwiGLU -------------------------------------------------------
@@ -109,8 +148,8 @@ def moe_apply(
     y_buf = jnp.einsum("ecf,efd->ecd", h, p["w_down"].astype(dt))
 
     # --- combine: gather back, weight, reduce over k -------------------------
-    y_flat = y_buf[e_flat, pos_c] * (w_flat * keep)[:, None].astype(dt)
-    y = y_flat.reshape(t, k, d).sum(axis=1).reshape(b, s, d)
+    y_flat = y_buf[e_buf, pos_c] * (w_flat * keep)[:, None].astype(dt)
+    y = _with_shared(p, cfg, x, y_flat.reshape(t, k, d).sum(axis=1).reshape(b, s, d))
 
     # --- aux losses -----------------------------------------------------------
     me = probs.mean(axis=0)                                   # (E,) mean router prob
@@ -134,28 +173,37 @@ def _moe_dropless(p: dict, cfg: ModelConfig, x: jax.Array, *, layer=None):
     only tiles of groups that have rows; taking a per-layer slice instead
     would copy all E experts of the layer, since the custom call cannot fuse
     a dynamic-slice operand.
+
+    With a share of the experts held (``experts_held`` < E), the stacks hold
+    ``held`` experts a layer and the groups are ``held`` a layer: the
+    assignments to experts held elsewhere sort after this chip's and are
+    counted in no group, so the grouped GEMMs never visit them.
     """
     m = cfg.moe
     dt = cfg.activation_dtype()
     b, s, d = x.shape
     t = b * s
-    e, k = m.num_experts, m.top_k
+    e, k, held = m.num_experts, m.top_k, m.held
 
     xf = x.reshape(t, d)
-    logits = L.dense(p["router"], xf.astype(jnp.float32))
-    top_logits, sel = jax.lax.top_k(logits, k)
-    weights = jax.nn.softmax(top_logits, axis=-1)
+    _, sel, weights = _route(p, cfg, xf)
 
     e_flat = sel.reshape(-1)
     w_flat = weights.reshape(-1)
+    if held < e:
+        here = e_flat < held
+        e_flat = jnp.where(here, e_flat, held)  # sorted after the held experts
     order = jnp.argsort(e_flat)  # stable in jnp
     inv = jnp.argsort(order)
     x_sorted = constrain(jnp.repeat(xf, k, axis=0)[order].astype(dt), "moe_tokens")
     w_gate, w_up, w_down = (p[name] for name in EXPERT_STACKS)
     if layer is None:  # one layer's experts: a stack of one
         w_gate, w_up, w_down, layer = w_gate[None], w_up[None], w_down[None], 0
-    n = w_gate.shape[0] * e
-    group_sizes = jnp.bincount(e_flat + layer * e, length=n).astype(jnp.int32)
+    n = w_gate.shape[0] * held
+    ids = e_flat + layer * held
+    if held < e:
+        ids = jnp.where(here, ids, n)  # in no group: bincount drops ids >= n
+    group_sizes = jnp.bincount(ids, length=n).astype(jnp.int32)
     w_gate, w_up, w_down = (w.reshape(n, *w.shape[2:]) for w in (w_gate, w_up, w_down))
 
     g = jax.lax.ragged_dot(x_sorted, w_gate.astype(dt), group_sizes)
@@ -164,5 +212,7 @@ def _moe_dropless(p: dict, cfg: ModelConfig, x: jax.Array, *, layer=None):
     y_sorted = jax.lax.ragged_dot(h, w_down.astype(dt), group_sizes)
 
     y_flat = y_sorted[inv] * w_flat[:, None].astype(dt)
-    y = y_flat.reshape(t, k, d).sum(axis=1).reshape(b, s, d)
+    if held < e:  # rows in no group: whatever the grouped GEMM left there
+        y_flat = jnp.where(here[:, None], y_flat, 0)
+    y = _with_shared(p, cfg, x, y_flat.reshape(t, k, d).sum(axis=1).reshape(b, s, d))
     return y.astype(x.dtype), jnp.zeros((), jnp.float32)

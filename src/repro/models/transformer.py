@@ -20,6 +20,7 @@ from repro.dist import compression
 from repro.dist.context import constrain
 from repro.kernels import ops
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models.moe import EXPERT_STACKS
 
 __all__ = [
@@ -35,6 +36,8 @@ __all__ = [
     "stack_decode",
     "init_cache",
     "fill_cache",
+    "init_pages",
+    "page_leaves",
     "remat_wrap",
 ]
 
@@ -45,6 +48,8 @@ __all__ = [
 
 
 def attn_init(key, cfg: ModelConfig, *, d_in: Optional[int] = None) -> dict:
+    if cfg.mla is not None:
+        return MLA.mla_init(key, cfg, d_in=d_in)
     d = d_in or cfg.d_model
     hd = cfg.hd
     kq, kk, kv, ko = L.split_keys(key, 4)
@@ -83,7 +88,12 @@ def attn_apply(
     use_rope: bool = True,
     return_kv: bool = False,
 ):
-    """Full-sequence attention (train / prefill / encoder / cross)."""
+    """Full-sequence attention (train / prefill / encoder / cross).
+    ``return_kv`` also returns what the cache keeps: (k, v), or (latent,)
+    under latent attention."""
+    if cfg.mla is not None:
+        assert kv_src is None and use_rope, "latent attention is causal self-attention"
+        return _mla_apply(p, cfg, x, positions, causal=causal, return_kv=return_kv)
     cross = kv_src is not None
     kv_src = x if kv_src is None else kv_src
     kv_positions = positions if kv_positions is None else kv_positions
@@ -110,6 +120,82 @@ def attn_apply(
     return out
 
 
+def _mla_apply(p: dict, cfg: ModelConfig, x, positions, *, causal: bool, return_kv: bool):
+    """Latent attention over the full sequence: K and V decompressed per
+    head (``mla.decompress``); V is zero-padded to the query-key width so
+    the one attention op serves, and cut back after it."""
+    q_nope, q_pe, latent = MLA.project(p, cfg, x, positions)
+    q, k, v = MLA.decompress(p, cfg, q_nope, q_pe, latent)
+    dv = v.shape[-1]
+    o = ops.attention(
+        q,
+        k,
+        jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, q.shape[-1] - dv))),
+        order=cfg.attn_order,
+        snake_group=cfg.snake_group,
+        causal=causal,
+        scale=MLA.softmax_scale(cfg.mla),
+        q_block=cfg.q_block,
+        kv_block=cfg.kv_block,
+        impl=cfg.attn_impl,
+        score_dtype=cfg.score_dtype,
+        bwd_q_block=cfg.bwd_q_block,
+        bwd_kv_block=cfg.bwd_kv_block,
+    )[..., :dv]
+    b, s = o.shape[:2]
+    out = L.dense(p["wo"], o.reshape(b, s, -1), dtype=cfg.activation_dtype())
+    return (out, (latent,)) if return_kv else out
+
+
+def _mla_decode(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict):
+    """Latent attention against a latent cache, in the absorbed form: the
+    new tokens' latents are written, and the absorbed queries attend the
+    cached latents directly (V is their first ``kv_lora_rank`` columns).
+    Paged: ragged C-position chunks, as ``_attn_decode_paged``; contiguous:
+    one position."""
+    b, c, _ = x.shape
+    lens = cache["len"]
+    paged = "latent_pages" in cache
+    if paged:
+        q_lens = cache.get("q_len")
+        if q_lens is None:
+            q_lens = jnp.full((b,), c, jnp.int32)
+        positions = lens[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    else:
+        assert c == 1, "contiguous decode takes a single query position"
+        positions = jnp.full((b, 1), lens)
+    q_nope, q_pe, latent = MLA.project(p, cfg, x, positions)
+    q = MLA.absorb_query(p, cfg, q_nope, q_pe)
+    cache = dict(cache)
+    kw = {}
+    if paged:
+        cache = _paged_write(cfg, cache, {"latent_pages": latent[:, :, None]}, lens, q_lens)
+        cache["len"] = lens + q_lens
+        bt = cache["block_table"]
+        capacity = bt.shape[1] * cache["latent_pages"].shape[2]
+        valid = jnp.minimum(lens + q_lens, capacity)
+        pool = cache["latent_pages"]
+        kw = dict(block_table=bt, q_lens=q_lens, order_group=cache.get("order_group"))
+    else:
+        cache = _cache_write(cfg, cache, "latent", latent[:, :, None], lens)
+        cache["len"] = lens + 1
+        valid = lens + 1
+        pool = cache["latent"]
+    o = ops.attention_decode(
+        q,
+        pool,
+        None,
+        valid,
+        order=cfg.attn_order,
+        snake_group=cfg.snake_group,
+        impl=cfg.attn_impl,
+        scale=MLA.softmax_scale(cfg.mla),
+        v_dim=cfg.mla.kv_lora_rank,
+        **kw,
+    )
+    return MLA.latent_output(p, cfg, o), cache
+
+
 def attn_decode(
     p: dict,
     cfg: ModelConfig,
@@ -125,6 +211,8 @@ def attn_decode(
     one call serves decode rows (q_len 1) and chunked-prefill rows (q_len
     up to C) together. The contiguous layouts stay single-token.
     """
+    if cfg.mla is not None and not cross:
+        return _mla_decode(p, cfg, x, cache)
     dt = cfg.activation_dtype()
     b, one, _ = x.shape
     hd = cfg.hd
@@ -166,22 +254,26 @@ def attn_decode(
     return out, cache
 
 
-def _paged_write(cfg: ModelConfig, cache: dict, k, v, starts, q_lens) -> dict:
+def _paged_write(cfg: ModelConfig, cache: dict, vals: dict, starts, q_lens) -> dict:
     """Chunked write-at-offset into a paged cache — THE paged write path.
 
-    Pages are laid out (n_pages, Hkv, page, hd) (int8 scales (n_pages, Hkv,
-    page)), so a token lands at ``[phys, :, offset]``.
-    k/v: (B, C, Hkv, hd) chunk values; row b's positions ``starts[b] + t``
-    for ``t < q_lens[b]`` are written through the block table (logical page
-    ``pos // page``, offset ``pos % page``). Invalid chunk rows (``t >=
-    q_len`` — padding of a ragged step, or inactive serve slots) are routed
-    to the reserved dummy page 0, so the fixed-shape scatter stays total.
-    Both prefill (``fill_cache``: starts 0, q_lens = S) and ragged serve
-    steps (decode rows at C=1, prefill chunks at C>1) funnel through here.
+    Pages are laid out (n_pages, heads, page, width) (int8 scales (n_pages,
+    Hkv, page)), so a token lands at ``[phys, :, offset]``.
+    ``vals`` maps each page array of ``cache`` to its (B, C, heads, width)
+    chunk values (``k_pages``/``v_pages``, or one ``latent_pages``, whose
+    narrower latents are zero-padded to the page rows' width); row b's
+    positions ``starts[b] + t`` for ``t < q_lens[b]`` are written through
+    the block table (logical page ``pos // page``, offset ``pos % page``).
+    Invalid chunk rows (``t >= q_len`` — padding of a ragged step, or
+    inactive serve slots) are routed to the reserved dummy page 0, so the
+    fixed-shape scatter stays total. Both prefill (``fill_cache``: starts
+    0, q_lens = S) and ragged serve steps (decode rows at C=1, prefill
+    chunks at C>1) funnel through here.
     """
-    b, c = k.shape[:2]
+    first = next(iter(vals))
+    b, c = vals[first].shape[:2]
     bt = cache["block_table"]
-    page = cache["k_pages"].shape[2]
+    page = cache[first].shape[2]
     capacity = bt.shape[1] * page
     tq = jnp.arange(c, dtype=jnp.int32)[None, :]
     pos = starts[:, None] + tq                             # (B, C)
@@ -193,7 +285,10 @@ def _paged_write(cfg: ModelConfig, cache: dict, k, v, starts, q_lens) -> dict:
     phys = jnp.where(valid, phys, 0)                       # dummy page 0
 
     out = dict(cache)
-    for name, val in (("k_pages", k), ("v_pages", v)):
+    for name, val in vals.items():
+        pad = out[name].shape[-1] - val.shape[-1]
+        if pad:
+            val = jnp.pad(val, ((0, 0),) * (val.ndim - 1) + ((0, pad),))
         if cfg.kv_cache_dtype == "int8":
             qv, sc = _quantize_kv(val)                     # (B,C,H,hd),(B,C,H)
             out[name] = out[name].at[phys, :, offset].set(qv)
@@ -225,7 +320,7 @@ def _attn_decode_paged(cfg: ModelConfig, cache: dict, q, k, v):
     k = L.rope(k, positions, theta=cfg.rope_theta)
 
     cache = dict(cache)
-    cache = _paged_write(cfg, cache, k, v, lens, q_lens)
+    cache = _paged_write(cfg, cache, {"k_pages": k, "v_pages": v}, lens, q_lens)
     cache["len"] = lens + q_lens
 
     valid = jnp.minimum(lens + q_lens, capacity)
@@ -275,15 +370,76 @@ def page_geometry(cfg: ModelConfig, max_len: int) -> tuple[int, int]:
     return page, -(-max_len // page)
 
 
+PAGE_LANES = 128  # a latent page row's width is a multiple of the TPU's lanes
+
+
+def page_leaves(cfg: ModelConfig, n_pages: int, page: int, *, lead=(), dtype=None) -> dict:
+    """A paged cache's page arrays, each (*lead, n_pages, heads, page,
+    width): ``k_pages`` and ``v_pages`` (int8 adds ``*_scale`` (*lead,
+    n_pages, Hkv, page), at ones), or under latent attention one
+    ``latent_pages`` of a single head, whose page tile the paged kernel
+    reads as K and, in its first ``kv_lora_rank`` columns, as V.
+
+    A latent row is ``latent_dim`` values (576 at DeepSeek-V2-Lite's
+    widths) zero-padded to a multiple of 128 lanes (640): at 576 the TPU
+    lays the pool out with the page rows minor (no lane padding), and the
+    kernel, which reads rows, then needs a transposed copy of every layer's
+    pages in every step."""
+    dt = dtype or cfg.activation_dtype()
+    if cfg.mla is not None:
+        if cfg.kv_cache_dtype == "int8":
+            raise ValueError("the latent cache is kept in the activation dtype, not int8")
+        width = -(-cfg.mla.latent_dim // PAGE_LANES) * PAGE_LANES
+        return {"latent_pages": jnp.zeros((*lead, n_pages, 1, page, width), dt)}
+    shape = (*lead, n_pages, cfg.n_kv_heads, page, cfg.hd)
+    pages = {}
+    if cfg.kv_cache_dtype == "int8":
+        for name in ("k_pages", "v_pages"):
+            pages[name] = jnp.zeros(shape, jnp.int8)
+            pages[name + "_scale"] = jnp.ones(shape[:-1], jnp.float32)
+    else:
+        for name in ("k_pages", "v_pages"):
+            pages[name] = jnp.zeros(shape, dt)
+    return pages
+
+
+# Cache leaves of the leading dense stack carry this prefix in the model's
+# cache pytree (``models.model``); the per-row metadata is shared by all
+# layers.
+DENSE_PREFIX = "dense_"
+CACHE_META = ("len", "block_table", "q_len", "order_group")
+
+
+def layer_stacks(cfg: ModelConfig, n_layers: Optional[int] = None) -> tuple:
+    """(cache prefix, layers) of each layer stack in order: the leading
+    dense stack (``first_dense_layers``), if any, then the main stack."""
+    n = cfg.n_layers if n_layers is None else n_layers
+    k = cfg.first_dense_layers
+    return ((DENSE_PREFIX, k), ("", n - k)) if k else (("", n),)
+
+
+def init_pages(cfg: ModelConfig, n_layers: int, n_pages: int, page: int, *, dtype=None) -> dict:
+    """A serving pool's page arrays for ``n_layers`` layers: each stack's
+    ``page_leaves`` stacked on a leading layer axis, named with its prefix."""
+    return {
+        prefix + name: leaf
+        for prefix, n in layer_stacks(cfg, n_layers)
+        for name, leaf in page_leaves(cfg, n_pages, page, lead=(n,), dtype=dtype).items()
+    }
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, dtype=None) -> dict:
     """Self-attention KV cache; SWA archs get a window-sized ring buffer.
     kv_cache_dtype='int8' stores quantized values + per-vector scales.
+    Latent attention caches one ``latent`` (B, S, 1, latent_dim) instead of
+    K and V.
 
-    ``cfg.kv_layout == 'paged'`` switches to a page-pool layout: k/v pages
-    (n_pages, Hkv, page, hd) plus a per-row ``block_table`` (B, n_blocks)
-    initialized to the identity mapping (row i owns pages [i*n, (i+1)*n)),
-    and per-row ``len`` (B,). A serving pool (repro.serve.kv_pool) re-maps
-    block tables as sequences join and leave the running batch.
+    ``cfg.kv_layout == 'paged'`` switches to a page-pool layout: page arrays
+    (``page_leaves``: k/v pages (n_pages, Hkv, page, hd)) plus a per-row
+    ``block_table`` (B, n_blocks) initialized to the identity mapping (row i
+    owns pages [i*n, (i+1)*n)), and per-row ``len`` (B,). A serving pool
+    (repro.serve.kv_pool) re-maps block tables as sequences join and leave
+    the running batch.
     """
     if cfg.kv_layout == "paged":
         if cfg.window is not None:
@@ -292,25 +448,21 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, dtype=None) -> dic
                 "archs keep the ring-buffer layout (kv_layout='contiguous')"
             )
         page, bpr = page_geometry(cfg, max_len)
-        shape = (batch * bpr, cfg.n_kv_heads, page, cfg.hd)
         cache = {
             "len": jnp.zeros((batch,), jnp.int32),
             "block_table": jnp.arange(batch * bpr, dtype=jnp.int32).reshape(
                 batch, bpr
             ),
         }
-        if cfg.kv_cache_dtype == "int8":
-            for name in ("k_pages", "v_pages"):
-                cache[name] = jnp.zeros(shape, jnp.int8)
-                cache[name + "_scale"] = jnp.ones(shape[:3], jnp.float32)
-        else:
-            dt = dtype or cfg.activation_dtype()
-            cache["k_pages"] = jnp.zeros(shape, dt)
-            cache["v_pages"] = jnp.zeros(shape, dt)
+        cache.update(page_leaves(cfg, batch * bpr, page, dtype=dtype))
         return cache
     size = min(max_len, cfg.window) if cfg.window is not None else max_len
-    shape = (batch, size, cfg.n_kv_heads, cfg.hd)
     cache = {"len": jnp.zeros((), jnp.int32)}
+    if cfg.mla is not None:
+        dt = dtype or cfg.activation_dtype()
+        cache["latent"] = jnp.zeros((batch, size, 1, cfg.mla.latent_dim), dt)
+        return cache
+    shape = (batch, size, cfg.n_kv_heads, cfg.hd)
     if cfg.kv_cache_dtype == "int8":
         for name in ("k", "v"):
             cache[name] = jnp.zeros(shape, jnp.int8)
@@ -338,19 +490,22 @@ def _cache_write(cfg: ModelConfig, cache: dict, name: str, val: jax.Array, pos) 
     return out
 
 
-def fill_cache(cfg: ModelConfig, cache: dict, k: jax.Array, v: jax.Array) -> dict:
-    """Write prefill K/V into a fresh cache (handles SWA truncation).
+def fill_cache(cfg: ModelConfig, cache: dict, k: jax.Array, v: Optional[jax.Array] = None) -> dict:
+    """Write prefill K/V (or, under latent attention, the latents ``k`` (B,
+    S, latent_dim) alone) into a fresh cache (handles SWA truncation).
 
     Paged caches must come straight from :func:`init_cache` (identity block
     table): row i's logical pages are then physically contiguous, so the
     prefill scatter is a reshape.
     """
-    if "k_pages" in cache:
-        return _fill_cache_paged(cfg, cache, k, v)
+    vals = {"k": k, "v": v} if v is not None else {"latent": k[:, :, None]}
+    if "block_table" in cache:
+        return _fill_cache_paged(cfg, cache, vals)
     s = k.shape[1]
-    size = cache["k"].shape[1]
+    first = next(iter(vals))
+    size = cache[first].shape[1]
     if s >= size:
-        k, v = k[:, -size:], v[:, -size:]
+        vals = {n: val[:, -size:] for n, val in vals.items()}
         if cfg.window is not None:
             # Ring-buffer layout: decode writes position p at index p % size,
             # so the kept tail (positions s-size..s-1) must land on those
@@ -359,26 +514,25 @@ def fill_cache(cfg: ModelConfig, cache: dict, k: jax.Array, v: jax.Array) -> dic
             # index p % size.
             shift = s % size
             if shift:
-                k = jnp.roll(k, shift, axis=1)
-                v = jnp.roll(v, shift, axis=1)
-    cache = _cache_write(cfg, cache, "k", k, 0)
-    cache = _cache_write(cfg, cache, "v", v, 0)
+                vals = {n: jnp.roll(val, shift, axis=1) for n, val in vals.items()}
+    for name, val in vals.items():
+        cache = _cache_write(cfg, cache, name, val, 0)
     cache["len"] = jnp.asarray(s, jnp.int32)
     return cache
 
 
-def _fill_cache_paged(cfg: ModelConfig, cache: dict, k: jax.Array, v: jax.Array) -> dict:
-    b, s = k.shape[:2]
-    page = cache["k_pages"].shape[2]
+def _fill_cache_paged(cfg: ModelConfig, cache: dict, vals: dict) -> dict:
+    first = next(iter(vals))
+    b, s = vals[first].shape[:2]
+    page = cache[first + "_pages"].shape[2]
     capacity = cache["block_table"].shape[1] * page
     if s > capacity:
-        k, v = k[:, -capacity:], v[:, -capacity:]
+        vals = {n: val[:, -capacity:] for n, val in vals.items()}
         s = capacity
     out = _paged_write(
         cfg,
         cache,
-        k,
-        v,
+        {n + "_pages": val for n, val in vals.items()},
         jnp.zeros((b,), jnp.int32),
         jnp.full((b,), s, jnp.int32),
     )
@@ -403,10 +557,7 @@ def ffn_init(key, cfg: ModelConfig, *, d_ff: Optional[int] = None) -> dict:
 
 
 def ffn_apply(p: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    dt = cfg.activation_dtype()
-    g = L.dense(p["w_gate"], x, dtype=dt)
-    u = L.dense(p["w_up"], x, dtype=dt)
-    return L.dense(p["w_down"], jax.nn.silu(g) * u, dtype=dt)
+    return L.swiglu(p, x, dtype=cfg.activation_dtype())
 
 
 # --------------------------------------------------------------------------
@@ -511,7 +662,7 @@ def _split_expert_stacks(cfg: ModelConfig, params: dict):
     indices.
     """
     ffn = params["ffn"]
-    if cfg.moe is None or not cfg.moe_serve_dropless or ffn["w_gate"].ndim != 4:
+    if cfg.moe is None or not cfg.moe_serve_dropless or getattr(ffn["w_gate"], "ndim", 0) != 4:
         return params, None, None
     stacks = {k: ffn[k] for k in EXPERT_STACKS}
     rest = {k: v for k, v in ffn.items() if k not in stacks}
@@ -546,14 +697,14 @@ def stack_prefill(
     def body(h, xs):
         lp, layer = xs
         xn = L.rmsnorm(lp["ln_attn"], h, cfg.norm_eps)
-        a, (k, v) = attn_apply(
+        a, kv = attn_apply(
             lp["attn"], cfg, xn, positions=positions, causal=True, return_kv=True
         )
         h = h + a
         y = _serve_ffn(
             ffn_fn, cfg, lp, stacks, layer, L.rmsnorm(lp["ln_ffn"], h, cfg.norm_eps)
         )
-        cache = fill_cache(cfg, init_cache(cfg, b, max_len), k, v)
+        cache = fill_cache(cfg, init_cache(cfg, b, max_len), *kv)
         return constrain(h + y, "residual"), cache
 
     body = remat_wrap(body, cfg)

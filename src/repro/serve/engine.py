@@ -447,6 +447,7 @@ class ServeEngine:
         self._m_tok_decode = r.counter("serve.step.tokens", kind="decode")
         self._m_tok_prefill = r.counter("serve.step.tokens", kind="prefill")
         self._m_kv_tok = r.counter("serve.step.kv_tokens")
+        self._m_qk_pairs = r.counter("serve.step.qk_pairs")
         self._m_generated = r.counter("serve.tokens.generated")
         self._m_steps_wide = r.counter("serve.steps", width="wide")
         self._m_steps_narrow = r.counter("serve.steps", width="narrow")
@@ -1245,9 +1246,12 @@ class ServeEngine:
                 bases = counts.copy()
                 n_decode = n_prefill = 0
                 n_kv = 0  # keys the step's queries attend, over its rows
+                n_qk = 0  # query-key pairs they attend: each query sees the
+                #           row's cached keys and the chunk's up to its own
                 for it in plan:
                     st = sched.slots[it.slot]
                     n_kv += int(pool.lens[it.slot]) + it.q_len
+                    n_qk += int(pool.lens[it.slot]) * it.q_len + it.q_len * (it.q_len + 1) // 2
                     if it.is_prefill:
                         seg = st.prompt[st.prompt_pos : st.prompt_pos + it.q_len]
                         tokens[it.slot, : len(seg)] = seg
@@ -1350,6 +1354,7 @@ class ServeEngine:
                     self._m_tok_decode.inc(n_decode)
                     self._m_tok_prefill.inc(n_prefill)
                     self._m_kv_tok.inc(n_kv)
+                    self._m_qk_pairs.inc(n_qk)
                     (self._m_steps_wide if width > 1 else self._m_steps_narrow).inc()
                     for it in plan:
                         st = sched.slots[it.slot]

@@ -237,18 +237,15 @@ class PagedKVPool:
         self.alloc = PagePool(n_pages + 1, faults=faults)  # +1 dummy page 0
         self.faults = faults
 
-        # (L, n_pages, Hkv, page, hd): a page is one contiguous block, and
-        # its per-head (page, hd) tiles are what the paged kernel DMAs.
-        shape = (n_layers, self.alloc.n_pages, cfg.n_kv_heads, self.page, cfg.hd)
-        self.pages: dict[str, jax.Array] = {}
-        if cfg.kv_cache_dtype == "int8":
-            for name in ("k_pages", "v_pages"):
-                self.pages[name] = jnp.zeros(shape, jnp.int8)
-                self.pages[name + "_scale"] = jnp.ones(shape[:4], jnp.float32)
-        else:
-            dt = dtype or cfg.activation_dtype()
-            for name in ("k_pages", "v_pages"):
-                self.pages[name] = jnp.zeros(shape, dt)
+        # The model names and shapes the page arrays (``T.init_pages``):
+        # (L, n_pages, heads, page, width) each — K and V for llama
+        # attention, one latent for latent attention — so a page is one
+        # contiguous block, and its per-head (page, width) tiles are what
+        # the paged kernel DMAs.
+        self.n_layers = n_layers
+        self.pages: dict[str, jax.Array] = T.init_pages(
+            cfg, n_layers, self.alloc.n_pages, self.page, dtype=dtype
+        )
 
         self.block_tables = np.zeros((n_slots, self.blocks_per_seq), np.int32)
         self.lens = np.zeros((n_slots,), np.int32)
@@ -651,9 +648,8 @@ class PagedKVPool:
     def caches_view(self, q_lens=None) -> dict:
         """Cache pytree for ``decode_step``: pages + current tables/lens
         (host-authoritative), via :func:`assemble_cache_view`."""
-        n_layers = next(iter(self.pages.values())).shape[0]
         return assemble_cache_view(
-            self.pages, self.block_tables, self.lens, n_layers, q_lens
+            self.pages, self.block_tables, self.lens, self.n_layers, q_lens
         )
 
     def update_pages(self, caches: dict) -> None:

@@ -72,6 +72,7 @@ def test_one_train_step_updates_params(arch):
     "arch",
     [
         "deepseek-7b",
+        "deepseek-v2-lite",
         "olmoe-1b-7b",
         "mixtral-8x7b",
         "qwen2-72b",

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.models import layers as L
 from repro.models import moe
 
 
@@ -178,3 +179,89 @@ def test_serve_scan_slices_no_expert_stack(arch):
         closed = {tuple(v.aval.shape) for v in scan.invars[: scan.params["num_consts"]]}
         assert stacks <= closed
         assert (cfg.n_layers,) in scanned  # the layer index rides the scan
+
+
+# ---- DeepSeek-V2's expert layer: shared experts, unnormalised top-k, a chip's share
+
+
+def _frozen_dropless(p, cfg, x):
+    """The dropless path as it was before shares, shared experts and
+    unnormalised weights were added: the oracle that ``experts_held=None``
+    must match bit for bit."""
+    m = cfg.moe
+    dt = cfg.activation_dtype()
+    b, s, d = x.shape
+    t = b * s
+    e, k = m.num_experts, m.top_k
+    xf = x.reshape(t, d)
+    logits = xf.astype(jnp.float32) @ p["router"]["w"]
+    top_logits, sel = jax.lax.top_k(logits, k)
+    weights = jax.nn.softmax(top_logits, axis=-1)
+    e_flat = sel.reshape(-1)
+    w_flat = weights.reshape(-1)
+    order = jnp.argsort(e_flat)
+    inv = jnp.argsort(order)
+    x_sorted = jnp.repeat(xf, k, axis=0)[order].astype(dt)
+    group_sizes = jnp.bincount(e_flat, length=e).astype(jnp.int32)
+    g = jax.lax.ragged_dot(x_sorted, p["w_gate"].astype(dt), group_sizes)
+    u = jax.lax.ragged_dot(x_sorted, p["w_up"].astype(dt), group_sizes)
+    y_sorted = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["w_down"].astype(dt), group_sizes)
+    y_flat = y_sorted[inv] * w_flat[:, None].astype(dt)
+    return y_flat.reshape(t, k, d).sum(axis=1).reshape(b, s, d).astype(x.dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_dropless_without_a_share_is_bit_equal_to_before(dtype):
+    cfg = get_config("olmoe-1b-7b").reduced().with_(dtype=dtype, param_dtype=dtype)
+    assert cfg.moe.experts_held is None and cfg.moe.norm_topk_prob
+    p = moe.moe_init(jax.random.PRNGKey(5), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 9, cfg.d_model)).astype(dtype)
+    got, _ = jax.jit(lambda p, x: moe.moe_apply(p, cfg, x, dropless=True))(p, x)
+    want = jax.jit(lambda p, x: _frozen_dropless(p, cfg, x))(p, x)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def _v2_cfg(held=None):
+    """DeepSeek-V2's expert layer at small width: 8 routed experts, top-2,
+    2 shared experts, weights not renormalised."""
+    cfg = get_config("deepseek-v2-lite").reduced()
+    return cfg.with_(moe=dataclasses.replace(cfg.moe, num_experts=8, top_k=2, experts_held=held))
+
+
+@pytest.mark.parametrize("dropless", [True, False], ids=["dropless", "capacity"])
+def test_four_shares_and_the_shared_experts_add_up_to_the_whole_layer(dropless):
+    """Four chips of an expert-parallel deployment, each holding 2 of the 8
+    routed experts: every chip routes over all 8 and computes its own
+    experts' part, and every chip computes the shared experts alike. The
+    routed parts of the four shares, plus the shared experts once, equal
+    the uncut layer. Chip i holds experts [2i, 2i + 2); the program holds
+    ids [0, held), so chip i is the program with the router's columns and
+    the expert stacks rolled by 2i."""
+    whole_cfg = _v2_cfg()
+    whole_cfg = whole_cfg.with_(moe=dataclasses.replace(whole_cfg.moe, capacity_factor=100.0))
+    share_cfg = whole_cfg.with_(moe=dataclasses.replace(whole_cfg.moe, experts_held=2))
+    p = moe.moe_init(jax.random.PRNGKey(7), whole_cfg)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 11, whole_cfg.d_model))
+    with jax.default_matmul_precision("highest"):
+        whole, _ = moe.moe_apply(p, whole_cfg, x, dropless=dropless)
+        shared = L.swiglu(p["shared"], x, dtype=whole_cfg.activation_dtype())
+        parts = []
+        for i in range(4):
+            ids = (jnp.arange(8) + 2 * i) % 8  # chip i's experts first
+            chip = {"router": {"w": p["router"]["w"][:, ids]},
+                    **{n: p[n][ids[:2]] for n in moe.EXPERT_STACKS}}
+            parts.append(moe.moe_apply(chip, share_cfg, x, dropless=dropless)[0])
+    # float32 at HIGHEST precision: the same terms, summed in another order
+    np.testing.assert_allclose(np.asarray(sum(parts) + shared), np.asarray(whole),
+                               rtol=1e-5, atol=1e-5)
+    assert float(jnp.abs(parts[0]).max()) > 0  # each share computes a part
+
+
+def test_unnormalised_weights_are_the_full_softmax_probabilities():
+    cfg = _v2_cfg()
+    p = moe.moe_init(jax.random.PRNGKey(9), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(10), (5, cfg.d_model))
+    logits, sel, w = moe._route(p, cfg, x)
+    probs = jax.nn.softmax(logits, axis=-1)
+    np.testing.assert_allclose(np.asarray(w), np.asarray(jnp.take_along_axis(probs, sel, -1)))
+    assert (np.asarray(w.sum(-1)) < 1).all()
