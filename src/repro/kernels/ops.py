@@ -30,9 +30,10 @@ from repro.kernels import ref as kref
 from repro.kernels import flash_attention as kflash
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.flash_decode import flash_decode_fwd
+from repro.kernels.paged_write import paged_write_fwd
 from repro.kernels.ssd import ssd_fwd
 
-__all__ = ["attention", "attention_decode", "ssd", "default_impl"]
+__all__ = ["attention", "attention_decode", "paged_write", "ssd", "default_impl"]
 
 Impl = str  # 'auto' | 'pallas' | 'pallas_interpret' | 'xla' | 'jnp' | 'reference'
 
@@ -326,6 +327,40 @@ def attention_decode(
             v_dim=v_dim,
         )
     raise ValueError(f"unknown decode impl: {impl!r}")
+
+
+def paged_write(
+    pools: dict,
+    vals: dict,
+    block_table: jax.Array,
+    starts: jax.Array,
+    q_lens: jax.Array,
+    layer,
+    *,
+    impl: Impl = "auto",
+) -> dict:
+    """Write a ragged chunk's new rows into stacked page arrays: ``pools``
+    maps each name to its [L, n_pages, heads, page(, width)] stack, ``vals``
+    to its (B, C, heads(, width)) values; row b's positions ``starts[b] +
+    t``, ``t < q_lens[b]``, land at ``[layer, block_table[b, pos // page],
+    :, pos % page]``, and nothing else is written. On TPU the Pallas write
+    updates the stacks in place (``kernels/paged_write.py``); elsewhere one
+    scatter per array (``kernels/ref.py``)."""
+    impl = _resolve(impl)
+    names = list(vals)
+    args = (
+        tuple(pools[n] for n in names),
+        tuple(vals[n].astype(pools[n].dtype) for n in names),
+        block_table,
+        starts,
+        q_lens,
+        jnp.asarray(layer, jnp.int32),
+    )
+    if impl in ("pallas", "pallas_interpret"):
+        out = paged_write_fwd(*args, interpret=(impl == "pallas_interpret"))
+    else:
+        out = kref.paged_write_ref(*args)
+    return dict(zip(names, out))
 
 
 # --------------------------------------------------------------------------

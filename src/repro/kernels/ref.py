@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from repro.core.attention import decode_attention as _decode_full
 from repro.core.attention import mha_reference
 
-__all__ = ["flash_attention_ref", "decode_attention_ref", "ssd_ref"]
+__all__ = ["flash_attention_ref", "decode_attention_ref", "ssd_ref", "paged_write_ref"]
 
 
 def flash_attention_ref(
@@ -92,3 +92,30 @@ def ssd_ref(
     state, ys = jax.lax.scan(step, init, xs)
     y = jnp.moveaxis(ys, 0, 1).transpose(0, 1, 2, 3)  # (B,S,H,P)
     return y.astype(x.dtype), state
+
+
+def paged_write_ref(
+    pools: tuple,
+    vals: tuple,
+    block_table: jax.Array,
+    starts: jax.Array,
+    q_lens: jax.Array,
+    layer,
+) -> tuple:
+    """Oracle for kernels.paged_write: one scatter per page array on its
+    [L * n_pages, ...] view. Positions past ``q_lens`` or the block table's
+    capacity are dropped."""
+    c = vals[0].shape[1]
+    lead, page = pools[0].shape[:2], pools[0].shape[3]
+    n_blocks = block_table.shape[1]
+    t = jnp.arange(c, dtype=jnp.int32)[None, :]
+    pos = starts.astype(jnp.int32)[:, None] + t
+    valid = (t < q_lens[:, None]) & (pos < n_blocks * page)
+    phys = jnp.take_along_axis(block_table, jnp.minimum(pos // page, n_blocks - 1), axis=1)
+    ids = jnp.where(valid, layer * lead[1] + phys, lead[0] * lead[1])  # out of range: dropped
+    out = []
+    for pool, v in zip(pools, vals):
+        flat = pool.reshape((-1,) + pool.shape[2:])
+        flat = flat.at[ids, :, pos % page].set(v.astype(pool.dtype), mode="drop")
+        out.append(flat.reshape(pool.shape))
+    return tuple(out)

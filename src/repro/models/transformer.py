@@ -172,10 +172,10 @@ def _mla_decode(p: dict, cfg: ModelConfig, x: jax.Array, cache: dict):
         cache = _paged_write(cfg, cache, {"latent_pages": latent[:, :, None]}, lens, q_lens)
         cache["len"] = lens + q_lens
         bt = cache["block_table"]
-        capacity = bt.shape[1] * cache["latent_pages"].shape[2]
+        capacity = bt.shape[1] * cache["latent_pages"].shape[-2]
         valid = jnp.minimum(lens + q_lens, capacity)
-        pool = cache["latent_pages"]
-        kw = dict(block_table=bt, q_lens=q_lens, order_group=cache.get("order_group"))
+        pool, base = _paged_pool(cfg, cache, "latent_pages")
+        kw = dict(block_table=bt + base, q_lens=q_lens, order_group=cache.get("order_group"))
     else:
         cache = _cache_write(cfg, cache, "latent", latent[:, :, None], lens)
         cache["len"] = lens + 1
@@ -258,46 +258,54 @@ def _paged_write(cfg: ModelConfig, cache: dict, vals: dict, starts, q_lens) -> d
     """Chunked write-at-offset into a paged cache — THE paged write path.
 
     Pages are laid out (n_pages, heads, page, width) (int8 scales (n_pages,
-    Hkv, page)), so a token lands at ``[phys, :, offset]``.
+    Hkv, page)), so a token lands at ``[phys, :, offset]``; inside the serve
+    layer scan each page array is the whole [L, n_pages, ...] stack and
+    ``cache["layer"]`` the layer written (``stack_decode``).
     ``vals`` maps each page array of ``cache`` to its (B, C, heads, width)
     chunk values (``k_pages``/``v_pages``, or one ``latent_pages``, whose
     narrower latents are zero-padded to the page rows' width); row b's
     positions ``starts[b] + t`` for ``t < q_lens[b]`` are written through
     the block table (logical page ``pos // page``, offset ``pos % page``).
     Invalid chunk rows (``t >= q_len`` — padding of a ragged step, or
-    inactive serve slots) are routed to the reserved dummy page 0, so the
-    fixed-shape scatter stays total. Both prefill (``fill_cache``: starts
-    0, q_lens = S) and ragged serve steps (decode rows at C=1, prefill
-    chunks at C>1) funnel through here.
+    inactive serve slots) and positions past the table's capacity are not
+    written. Both prefill (``fill_cache``: starts 0, q_lens = S) and ragged
+    serve steps (decode rows at C=1, prefill chunks at C>1) funnel through
+    here; ``ops.paged_write`` writes the stack in place on TPU.
     """
-    first = next(iter(vals))
-    b, c = vals[first].shape[:2]
-    bt = cache["block_table"]
-    page = cache[first].shape[2]
-    capacity = bt.shape[1] * page
-    tq = jnp.arange(c, dtype=jnp.int32)[None, :]
-    pos = starts[:, None] + tq                             # (B, C)
-    valid = tq < q_lens[:, None]
-    wpos = jnp.minimum(pos, capacity - 1)  # clamp like the contiguous path
-    page_log = wpos // page
-    offset = wpos % page
-    phys = jnp.take_along_axis(bt, page_log, axis=1)       # (B, C)
-    phys = jnp.where(valid, phys, 0)                       # dummy page 0
-
-    out = dict(cache)
+    stacked = "layer" in cache  # else one layer's pools: a stack of one
+    new = {}
     for name, val in vals.items():
-        pad = out[name].shape[-1] - val.shape[-1]
+        pad = cache[name].shape[-1] - val.shape[-1]
         if pad:
             val = jnp.pad(val, ((0, 0),) * (val.ndim - 1) + ((0, pad),))
         if cfg.kv_cache_dtype == "int8":
-            qv, sc = _quantize_kv(val)                     # (B,C,H,hd),(B,C,H)
-            out[name] = out[name].at[phys, :, offset].set(qv)
-            out[name + "_scale"] = out[name + "_scale"].at[phys, :, offset].set(sc)
+            new[name], new[name + "_scale"] = _quantize_kv(val)  # (B,C,H,hd),(B,C,H)
         else:
-            out[name] = out[name].at[phys, :, offset].set(
-                val.astype(out[name].dtype)
-            )
+            new[name] = val
+    pools = ops.paged_write(
+        {n: cache[n] if stacked else cache[n][None] for n in new},
+        new, cache["block_table"], starts, q_lens, cache.get("layer", 0),
+        impl=cfg.attn_impl,
+    )
+    out = dict(cache)
+    out.update({n: p if stacked else p[0] for n, p in pools.items()})
     return out
+
+
+def _paged_pool(cfg: ModelConfig, cache: dict, name: str):
+    """(pool, first page id) of a page array as the paged attention reads
+    it: inside the serve layer scan the [L * n_pages, ...] view of the
+    stack, whose layer ``cache["layer"]`` starts at page ``layer *
+    n_pages`` (int8: that layer's pages, dequantized, at 0); else the
+    layer's own pool at 0."""
+    layer = cache.get("layer")
+    if layer is None:
+        return _cache_read(cfg, cache, name), 0
+    pool = cache[name]
+    if cfg.kv_cache_dtype == "int8":
+        scale = cache[name + "_scale"][layer]
+        return _dequantize_kv(pool[layer], scale, cfg.activation_dtype()), 0
+    return pool.reshape((-1,) + pool.shape[2:]), layer * pool.shape[1]
 
 
 def _attn_decode_paged(cfg: ModelConfig, cache: dict, q, k, v):
@@ -309,7 +317,7 @@ def _attn_decode_paged(cfg: ModelConfig, cache: dict, q, k, v):
     b, c = q.shape[:2]
     lens = cache["len"]  # (B,) tokens already cached (chunk positions follow)
     bt = cache["block_table"]
-    page = cache["k_pages"].shape[2]
+    page = cache["k_pages"].shape[-2]
     capacity = bt.shape[1] * page
     q_lens = cache.get("q_len")
     if q_lens is None:
@@ -324,19 +332,21 @@ def _attn_decode_paged(cfg: ModelConfig, cache: dict, q, k, v):
     cache["len"] = lens + q_lens
 
     valid = jnp.minimum(lens + q_lens, capacity)
+    k_pool, base = _paged_pool(cfg, cache, "k_pages")
+    v_pool, _ = _paged_pool(cfg, cache, "v_pages")
     # ``order_group`` rides the cache dict like ``q_len``: a traced
     # effective reversal-group scalar that overrides cfg.attn_order for
     # this step (the serve engine's runtime order switch; absent outside
     # the continuous path, where the static config order applies).
     o = ops.attention_decode(
         q,
-        _cache_read(cfg, cache, "k_pages"),
-        _cache_read(cfg, cache, "v_pages"),
+        k_pool,
+        v_pool,
         valid,
         order=cfg.attn_order,
         snake_group=cfg.snake_group,
         impl=cfg.attn_impl,
-        block_table=bt,
+        block_table=bt + base,
         q_lens=q_lens,
         order_group=cache.get("order_group"),
     )
@@ -401,6 +411,12 @@ def page_leaves(cfg: ModelConfig, n_pages: int, page: int, *, lead=(), dtype=Non
         for name in ("k_pages", "v_pages"):
             pages[name] = jnp.zeros(shape, dt)
     return pages
+
+
+def is_page_leaf(name: str) -> bool:
+    """Whether a cache leaf is a page array of ``page_leaves`` (pages or
+    their int8 scales), under any stack prefix."""
+    return "_pages" in name
 
 
 # Cache leaves of the leading dense stack carry this prefix in the model's
@@ -524,7 +540,7 @@ def fill_cache(cfg: ModelConfig, cache: dict, k: jax.Array, v: Optional[jax.Arra
 def _fill_cache_paged(cfg: ModelConfig, cache: dict, vals: dict) -> dict:
     first = next(iter(vals))
     b, s = vals[first].shape[:2]
-    page = cache[first + "_pages"].shape[2]
+    page = cache[first + "_pages"].shape[-2]
     capacity = cache["block_table"].shape[1] * page
     if s > capacity:
         vals = {n: val[:, -capacity:] for n, val in vals.items()}
@@ -720,19 +736,36 @@ def stack_decode(
     *,
     ffn_apply_fn=None,
 ):
-    """One-token step through all layers, updating stacked caches."""
+    """One-token step through all layers, updating stacked caches.
+
+    A paged cache's page arrays ([L, n_pages, ...] stacks, ``is_page_leaf``)
+    do not ride the scan as slices: sliced per layer they are copied out,
+    relaid out for the write and stacked back every layer of every step.
+    They are carried whole, the scan carries the layer index, and each layer
+    writes its rows into the stacks and reads its pages from them in place
+    (``_paged_write``, ``_paged_pool``). The per-row metadata and contiguous
+    caches are scanned as before."""
     ffn_fn = ffn_apply_fn or (lambda p, c, h: ffn_apply(p, c, h))
     scanned, stacks, layers = _split_expert_stacks(cfg, params)
+    pages = {n: v for n, v in caches.items() if is_page_leaf(n)}
+    rest = {n: v for n, v in caches.items() if n not in pages}
+    if pages and layers is None:
+        layers = jnp.arange(next(iter(pages.values())).shape[0], dtype=jnp.int32)
 
-    def body(h, xs):
+    def body(carry, xs):
+        h, pages = carry
         lp, cache, layer = xs
+        if pages:
+            cache = {**cache, **pages, "layer": layer}
         xn = L.rmsnorm(lp["ln_attn"], h, cfg.norm_eps)
         a, cache = attn_decode(lp["attn"], cfg, xn, cache)
+        pages = {n: cache[n] for n in pages}
+        cache = {n: v for n, v in cache.items() if n not in pages and n != "layer"}
         h = h + a
         y = _serve_ffn(
             ffn_fn, cfg, lp, stacks, layer, L.rmsnorm(lp["ln_ffn"], h, cfg.norm_eps)
         )
-        return h + y, cache
+        return (h + y, pages), cache
 
-    h, caches = layer_scan(cfg, body, x, (scanned, caches, layers))
-    return h, caches
+    (h, pages), caches = layer_scan(cfg, body, (x, pages), (scanned, rest, layers))
+    return h, {**caches, **pages}

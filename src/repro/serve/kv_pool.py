@@ -190,7 +190,7 @@ class PagedKVPool:
 
     The device side is a dict of stacked leaves shaped like the per-layer
     paged caches from ``transformer.init_cache`` with a leading layer axis,
-    which is exactly what ``stack_decode`` scans — ``caches_view()`` splices
+    which is exactly what ``stack_decode`` carries — ``caches_view()`` splices
     the host block tables and lengths in, and ``update_pages()`` takes the
     written pages back after a mixed step. K/V values are *produced* by the
     engine's ragged mixed step writing at per-row offsets

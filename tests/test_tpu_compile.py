@@ -252,19 +252,84 @@ def test_moe_mixed_step_reads_experts_in_place(one_chip, olmoe_cfg, width):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.2 * GiB
 
 
+def _latent_cfg():
+    cfg = get_config("deepseek-v2-lite").with_(attn_impl="pallas")
+    return cfg.with_(moe=dataclasses.replace(cfg.moe, experts_held=16))
+
+
 @pytest.mark.parametrize("width", [1, 512], ids=["narrow", "wide"])
 def test_latent_mixed_step_fits_one_chip(one_chip, width):
     """DeepSeek-V2-Lite's mixed step over the latent pool: the paged kernel
     (the name the benchmark reads) serves the latent attention, the pool
     keeps the TPU's row-major layout (no pool-shaped value with the page
     rows minor, which would be a transposed copy for the kernel), the
-    step's temporaries stay under 2.5 GiB (2.342 GiB at the chunk's width
-    when this test was written, most of it a copy of the 2.11 GiB pool),
-    and the weights, the pool and the step fit the chip."""
-    cfg = get_config("deepseek-v2-lite").with_(attn_impl="pallas")
-    cfg = cfg.with_(moe=dataclasses.replace(cfg.moe, experts_held=16))
-    compiled = _compile_mixed_step(one_chip, cfg, MLA_SLOTS, MLA_MAX_LEN, width, chunk=512)
+    step's temporaries stay under 0.45 GiB (0.394 GiB at the chunk's width
+    since the pool is written in place; 2.342 GiB, most of it a copy of
+    the 2.11 GiB pool, before), and the weights, the pool and the step fit
+    the chip."""
+    compiled = _compile_mixed_step(
+        one_chip, _latent_cfg(), MLA_SLOTS, MLA_MAX_LEN, width, chunk=512
+    )
     assert "paged_flash_decode_fwd" in _kernels(compiled)
     assert not re.search(r"bf16\[(1|26),513,1,128,640\]\{3,4,", compiled.as_text())
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * GiB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.45 * GiB
     assert _in_hbm(compiled) < HBM_BYTES
+
+
+# What a value shaped like a layer's pages or a stack of them may be in the
+# compiled step: the entry's pool, the scan's carry, views and the kernels
+# that read and write it in place. A copy, slice or fusion is a copy.
+POOL_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast", "custom-call"}
+
+
+def _pool_shapes(pages: dict) -> set:
+    """Each stack's shape, one layer's, and the [L * n_pages] view's."""
+    out = set()
+    for leaf in pages.values():
+        n, rest = leaf.shape[0], leaf.shape[1:]
+        out |= {leaf.shape, rest, (n * rest[0],) + rest[1:]}
+    return out
+
+
+MIXED_STEPS = {
+    # name: (config, slots, max_len, chunk, temporaries bound at either width)
+    "deepseek-7b": (lambda: get_config("deepseek-7b"), SLOTS, MAX_LEN, 256, 0.25),
+    "olmoe-1b-7b": (lambda: get_config("olmoe-1b-7b"), MOE_SLOTS, MOE_MAX_LEN, 512, 0.05),
+    "deepseek-v2-lite": (_latent_cfg, MLA_SLOTS, MLA_MAX_LEN, 512, 0.45),
+}
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["narrow", "chunk"])
+@pytest.mark.parametrize("model", list(MIXED_STEPS))
+def test_mixed_step_writes_pool_in_place(one_chip, model, wide):
+    """Each cell's mixed step carries the paged pool whole through the layer
+    scan: the new rows are written into the donated stack by the in-place
+    write (``paged_write``) and read from it by the paged kernel, so no
+    value shaped like one layer's pages or the stack is a copy, a slice, a
+    write-back or a fusion; the pool is aliased input to output, and the
+    step's temporaries are a small part of one pool (0.196 / 0.026 / 0.394
+    GiB at the chunk's width when this test was written, against 1.080 /
+    1.189 / 2.342 with the pool sliced per layer)."""
+    make, slots, max_len, chunk, bound = MIXED_STEPS[model]
+    cfg = make().with_(attn_impl="pallas")
+    compiled = _compile_mixed_step(
+        one_chip, cfg, slots, max_len, chunk if wide else 1, chunk=chunk
+    )
+    pages = jax.eval_shape(
+        lambda: T.init_pages(cfg, cfg.n_layers, slots * max_len // PAGE + 1, PAGE)
+    )
+    shapes = _pool_shapes(pages)
+    made = {
+        (op, dims)
+        for dims, op in (
+            (tuple(map(int, m.group(1).split(","))), m.group(2))
+            for m in re.finditer(r"= \w+\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(", compiled.as_text())
+        )
+        if dims in shapes
+    }
+    assert made and {op for op, _ in made} <= POOL_OPS, sorted(made)
+    assert {"paged_write", "paged_flash_decode_fwd"} <= _kernels(compiled)
+    m = compiled.memory_analysis()
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pages))
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < bound * GiB
